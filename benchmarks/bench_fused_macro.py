@@ -802,7 +802,7 @@ def run() -> dict:
             "geometry": f"{LARGE_N_IN}x{LARGE_N_OUT}", "batch": M,
             "virtual_macros": big_geo.n_macros,
             "tile_grid": list(big_plan.grid),
-            "vmem_resident_kb": round(big_plan.vmem_resident_bytes / 1024, 1),
+            "vmem_kb": round(big_plan.vmem_bytes / 1024, 1),
             "us_fused_tiled": round(us_big_fused, 1),
             "us_composed": round(us_big_composed, 1),
             "speedup": round(us_big_composed / us_big_fused, 2),

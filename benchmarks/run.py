@@ -14,6 +14,8 @@ import os
 import sys
 import time
 
+from repro import compile_cache
+
 from benchmarks import (ablation_k_sweep, ablation_kwn_lm,
                         bench_fused_macro, fig3d_weight_impl, fig5b_snl,
                         fig6c_nlq, fig7_ima, fig8_accuracy, fig9_energy,
@@ -36,6 +38,7 @@ BENCHES = {
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()

@@ -25,13 +25,14 @@ same serving-engine drain.  (Silicon fine-tuning stays single-layer: the
 stacked backward is a roadmap follow-up.)
 
     PYTHONPATH=src python examples/train_snn_events.py [--steps 150]
-        [--silicon-steps 60] [--stack 96,64]
+        [--silicon-steps 100] [--stack 96,64]
 """
 
 import argparse
 
 import jax
 
+from repro import compile_cache
 from repro.core import ima
 from repro.data import events as ev_lib
 from repro.models import snn
@@ -39,12 +40,13 @@ from repro.serve.engine import EventRequest, SNNEventEngine
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=150)
-    ap.add_argument("--silicon-steps", type=int, default=60,
+    ap.add_argument("--silicon-steps", type=int, default=100,
                     help="noise-aware QAT fine-tune steps through the fused "
                          "kernel (KWN mode; 0 disables the stage)")
-    ap.add_argument("--silicon-lr", type=float, default=0.02)
+    ap.add_argument("--silicon-lr", type=float, default=0.3)
     ap.add_argument("--dataset", default="nmnist",
                     choices=list(ev_lib.DATASETS))
     ap.add_argument("--serve-requests", type=int, default=96,

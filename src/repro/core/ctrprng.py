@@ -71,9 +71,11 @@ def _unit_open(bits: jax.Array) -> jax.Array:
     """uint32 -> f32 uniform on the open interval (0, 1).
 
     Uses the top 24 bits (exact in f32) shifted off zero by half an ulp so
-    ``log`` in Box–Muller never sees 0.
+    ``log`` in Box–Muller never sees 0.  The 24-bit word fits int32, and
+    the cast goes through it: the TPU kernel compiler has no uint32 -> f32
+    conversion.
     """
-    hi24 = (bits >> jnp.uint32(8)).astype(jnp.float32)
+    hi24 = (bits >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
     return (hi24 + jnp.float32(0.5)) * jnp.float32(2.0 ** -24)
 
 
@@ -101,7 +103,8 @@ def counter_sign(seed, step, rows: jax.Array, cols: jax.Array,
     k0 = jnp.asarray(seed).astype(jnp.uint32)
     k1 = jnp.uint32(tag) ^ jnp.asarray(step).astype(jnp.uint32)
     b0, _ = threefry2x32(k0, k1, rows, cols)
-    return (b0 & jnp.uint32(1)).astype(jnp.float32) * 2.0 - 1.0
+    bit = (b0 & jnp.uint32(1)).astype(jnp.int32)    # no uint32 -> f32 on TPU
+    return bit.astype(jnp.float32) * 2.0 - 1.0
 
 
 def noisy_ima_codes(ideal_codes: jax.Array, x: jax.Array,

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
     """GPipe bubble overhead: (S-1) idle ticks out of (M+S-1) total."""
@@ -67,8 +65,8 @@ def gpipe(stage_fn, params, x_mb: jax.Array, mesh: Mesh,
         outs = jnp.where(stage == n_stages - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, stage_axis)
 
-    fn = compat.shard_map(local_fn, mesh,
-                          in_specs=(jax.tree.map(lambda _: P(stage_axis),
-                                                 params), P()),
-                          out_specs=P())
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(jax.tree.map(lambda _: P(stage_axis),
+                                              params), P()),
+                       out_specs=P(), check_vma=False)
     return fn(params, x_mb)

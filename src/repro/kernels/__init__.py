@@ -13,7 +13,7 @@ flash_attention — online-softmax attention fwd with causal block skipping
               (beyond-paper: removes the 2x causal flops waste the roofline
               table shows for train/prefill attention; serving-prefill use).
 
-``ops``  — jit'd wrappers (padding, batching, interpret switch).
+``ops``  — jit'd wrappers (padding, batching, interpret_mode()).
 ``ref``  — pure-jnp oracles used by the allclose test sweeps.
 """
 

@@ -73,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True, bq: int = 128, bk: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        *, interpret: bool) -> jax.Array:
     """q,k,v: (BH, S, D) -> (BH, S, D).  S % bq == S % bk == 0."""
     bh, s, d = q.shape
     assert s % bq == 0 and s % bk == 0, (s, bq, bk)

@@ -81,13 +81,15 @@ single-macro layer (NC=N=128) costs
     v + noise + outputs ~6*128*128 f32 ~ 384 KB
 
 ~0.7 MB, and each additional column tile adds only 64 KB of accumulator +
-the same streamed 64 KB plane window — so a 256x512 layer (n_j=4) stays
-near 1 MB, far under the ~16 MB VMEM budget.  The head's transient
-``(bm, NC, 2^code_bits)`` one-hot compare (4 MB at NC=512, 5-bit codes) is
-the real ceiling: NC beyond ~1-2k columns per kernel should split at the
-model layer.  Folding T into the grid adds *no* VMEM (one time step is
-resident at a time); it removes the per-step kernel launch + weight-plane
-re-staging that dominates short-step event-stream serving.
+the same streamed 64 KB plane window.  The head works on 2-D ``(bm, NC)``
+planes only — one compare per ramp boundary, one select per LUT code, the
+KWN priority count as a 0/1 triangular contraction — so its temporaries
+grow with ``bm * NC`` (``TilePlan.vmem_bytes`` counts them).  On a TPU
+every launch compiles with ``vmem_limit_bytes()`` and ``plan_tiles``
+shrinks the row tile until the estimate fits.  Folding T into the grid
+adds *no* VMEM (one time step is resident at a time); it removes the
+per-step kernel launch + weight-plane re-staging that dominates short-step
+event-stream serving.
 
 Tile-shape / activity-granularity heuristic
 -------------------------------------------
@@ -136,6 +138,40 @@ DEFAULT_BN = 128  # the macro's column count: one col-tile == one macro width
 
 _LIF_STATICS = ("beta", "v_th1", "v_th2", "v_reset", "v_lim")
 
+# The ramp boundaries and LUT levels: whole 1-D arrays in SMEM, read as
+# scalars by the per-code loops of the head.
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+# Live (bm, NC) 32-bit temporaries of the fused head (``TilePlan.vmem_bytes``).
+HEAD_TEMPORARIES = 16
+
+
+def vmem_limit_bytes() -> int:
+    """Scoped-VMEM limit the fused kernels compile with on a TPU.
+
+    Three quarters of one TensorCore's VMEM on the present chip, as
+    ``pltpu.get_tpu_info`` reports it; that call raises for a TPU kind it
+    cannot size, and there is no default.  The last quarter stays free for
+    Mosaic's internal scratch.  Left unset, Mosaic would hold every kernel
+    to its 16 MiB default scope.
+    """
+    return pltpu.get_tpu_info().vmem_capacity_bytes * 3 // 4
+
+
+def compiler_params(interpret: bool):
+    """Mosaic compiler parameters (None in the interpreter)."""
+    if interpret:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes())
+
+
+def plan_vmem_budget() -> int | None:
+    """VMEM bytes a plan may take: the limit on a TPU, none when the kernels
+    run in the interpreter (the lazy import keeps ``ops`` -> this module
+    acyclic at import time)."""
+    from repro.kernels import ops
+    return None if ops.interpret_mode() else vmem_limit_bytes()
+
 
 # ---------------------------------------------------------------------------
 # Tile planning
@@ -147,10 +183,9 @@ class TilePlan(NamedTuple):
     n_pad/nc_pad are the padded per-neuron / per-column widths (nc_pad ==
     n_branches * n_pad, branch-major).  ``n_valid`` is the number of real
     columns the KWN sweep may admit (padded columns are excluded from the
-    ramp inside the kernel).  ``vmem_resident_bytes`` counts the blocks live
-    in VMEM per grid step (x + double-buffered weight planes + accumulator +
-    LIF state + per-step outputs), not the head's transient one-hots; the
-    activity map is SMEM-resident (``activity_bytes``) and not counted.
+    ramp inside the kernel).  ``vmem_bytes`` estimates the scoped VMEM one
+    launch needs on a TPU (see the property); the activity map is
+    SMEM-resident (``activity_bytes``) and not counted.
     ``activity_shape`` is the occupancy-map geometry the gated kernel
     prefetches: one int32 word per (step, row-tile, K-tile) block.
     """
@@ -166,11 +201,27 @@ class TilePlan(NamedTuple):
     grid: tuple[int, int, int, int]   # (M/bm, T, NC/bn, K/bk)
 
     @property
-    def vmem_resident_bytes(self) -> int:
-        streamed = self.bm * self.bk + 2 * self.bk * self.bn     # int8, x2 buf
-        resident = 4 * (self.bm * self.nc_pad                     # mac f32
-                        + 5 * self.bm * self.n_pad)               # v/noise/out
-        return 2 * streamed + resident
+    def vmem_bytes(self) -> int:
+        """Estimated scoped VMEM of one launch, in bytes, on a TPU.
+
+        Every block is counted at its native tile padding (f32 rows to 8,
+        int8 rows to 32, lanes to 128) and double-buffered by the Pallas
+        pipeline: the streamed int8 x block and weight planes, the (bm, N)
+        row blocks (membrane in/out, spikes, mask, SNL noise, training
+        trace, step counts, row_ctl) and the full-width (bm, NC) MAC
+        accumulator.  The head adds ``HEAD_TEMPORARIES`` live (bm, NC)
+        32-bit temporaries (ramp codes, counter-PRNG words, KWN sweep
+        state, LUT value, drive).  Calibrated against Mosaic's own
+        accounting; ``plan_tiles`` keeps it under ``vmem_limit_bytes()``.
+        """
+        rows = _ceil_mult(self.bm, 8)
+        lanes = lambda w: _ceil_mult(w, 128)
+        streamed = (_ceil_mult(self.bm, 32) * lanes(self.bk)
+                    + 2 * _ceil_mult(self.bk, 32) * lanes(self.bn))
+        row_blocks = 4 * rows * (6 * lanes(self.n_pad) + 2 * 128)
+        mac = 4 * rows * lanes(self.nc_pad)
+        head = HEAD_TEMPORARIES * 4 * rows * lanes(self.nc_pad)
+        return 2 * (streamed + row_blocks + mac) + head
 
     @property
     def activity_shape(self) -> tuple[int, int, int]:
@@ -261,32 +312,54 @@ def plan_tiles(m: int, k_dim: int, nc: int, n: int, t: int = 1, *,
     occupancy block is never padded-zero by construction and per-block
     gating stays meaningful; layers at or past 256 rows tile at the
     physical macro row count.
+
+    On a TPU every returned plan fits the kernels' VMEM limit
+    (``TilePlan.vmem_bytes`` <= ``vmem_limit_bytes()``): the row tile, then
+    the column tile, is halved until it does — overrides and cached plans
+    included.  In the interpreter there is no VMEM, and no bound.
     """
     if use_cache and bm is None and bk is None and bn is None:
         cached = cached_plan_blocks(m, k_dim, nc, n, t, mode=mode,
                                     density=density)
         if cached is not None:
             bm, bk, bn = cached
-    bm_ = bm or min(DEFAULT_BM, _ceil_mult(m, 8))
     bk_ = bk or (DEFAULT_BK if k_dim >= DEFAULT_BK else _ceil_mult(k_dim, 128))
-    bn_req = bn or DEFAULT_BN
-    if nc <= bn_req:
-        bn_ = nc
-        n_pad, nc_pad = n, nc
-    elif mode == "nld" and n_branches > 1:
-        bn_ = bn_req
-        step = bn_ // math.gcd(bn_, n_branches)
-        n_pad = _ceil_mult(n, step)
-        nc_pad = n_branches * n_pad
-    else:
-        bn_ = bn_req
-        nc_pad = _ceil_mult(nc, bn_)
-        n_pad = nc_pad
-    m_pad = _ceil_mult(m, bm_)
-    k_pad = _ceil_mult(k_dim, bk_)
-    return TilePlan(bm=bm_, bk=bk_, bn=bn_, m_pad=m_pad, k_pad=k_pad,
-                    n_pad=n_pad, nc_pad=nc_pad, n_valid=nc,
-                    grid=(m_pad // bm_, t, nc_pad // bn_, k_pad // bk_))
+
+    def make(bm_: int, bn_req: int) -> TilePlan:
+        if nc <= bn_req:
+            bn_ = nc
+            n_pad, nc_pad = n, nc
+        elif mode == "nld" and n_branches > 1:
+            bn_ = bn_req
+            step = bn_ // math.gcd(bn_, n_branches)
+            n_pad = _ceil_mult(n, step)
+            nc_pad = n_branches * n_pad
+        else:
+            bn_ = bn_req
+            nc_pad = _ceil_mult(nc, bn_)
+            n_pad = nc_pad
+        m_pad = _ceil_mult(m, bm_)
+        k_pad = _ceil_mult(k_dim, bk_)
+        return TilePlan(bm=bm_, bk=bk_, bn=bn_, m_pad=m_pad, k_pad=k_pad,
+                        n_pad=n_pad, nc_pad=nc_pad, n_valid=nc,
+                        grid=(m_pad // bm_, t, nc_pad // bn_, k_pad // bk_))
+
+    plan = make(bm or min(DEFAULT_BM, _ceil_mult(m, 8)), bn or DEFAULT_BN)
+    budget = plan_vmem_budget()
+    # On a TPU, halve the row tile (then the column tile) until the launch
+    # fits the chip's VMEM limit; a layer too wide for one 8-row launch is
+    # an error here, not a Mosaic refusal later.
+    while budget is not None and plan.vmem_bytes > budget:
+        if plan.bm > 8:
+            plan = make(_ceil_mult(plan.bm // 2, 8), plan.bn)
+        elif plan.bn > 128 and plan.bn % 256 == 0:
+            plan = make(plan.bm, plan.bn // 2)
+        else:
+            raise ValueError(
+                f"fused launch {m}x{k_dim}x{nc} needs ~{plan.vmem_bytes} "
+                f"bytes of VMEM at 8 rows, over the {budget}-byte limit; "
+                f"split the layer")
+    return plan
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +379,12 @@ def _accumulate_mac_tile(x_ref, msb_ref, lsb_ref, mac_ref, *, ratio: float,
     exact zeros is the identity).
     """
     j, kk = pl.program_id(2), pl.program_id(3)
-    col = (pl.dslice(0, 1), pl.dslice(None), pl.dslice(j * bn, bn))
+    # one column tile spans the whole accumulator: a static full slice
+    # (Mosaic needs no lane-offset proof for it); wider layers slice at a
+    # multiple of bn, which plan_tiles keeps lane-aligned
+    single = mac_ref.shape[-1] == bn
+    col = (slice(None), slice(None),
+           slice(None) if single else pl.ds(pl.multiple_of(j * bn, bn), bn))
 
     def _decoded_part():
         x = x_ref[0].astype(jnp.float32)
@@ -321,35 +399,72 @@ def _accumulate_mac_tile(x_ref, msb_ref, lsb_ref, mac_ref, *, ratio: float,
 
         @pl.when(kk == 0)
         def _init():
-            pl.store(mac_ref, col, jnp.zeros_like(part) + part)
+            mac_ref[col] = jnp.zeros_like(part) + part
 
         @pl.when(kk > 0)
         def _accumulate():
-            pl.store(mac_ref, col, pl.load(mac_ref, col) + part)
+            mac_ref[col] = mac_ref[col] + part
         return
 
     @pl.when(kk == 0)
     def _zero():
-        pl.store(mac_ref, col,
-                 jnp.zeros((1, x_ref.shape[1], bn), jnp.float32))
+        mac_ref[col] = jnp.zeros((1, x_ref.shape[1], bn), jnp.float32)
 
     @pl.when(occ > 0)
     def _mac():
-        pl.store(mac_ref, col, pl.load(mac_ref, col) + _decoded_part())
+        mac_ref[col] = mac_ref[col] + _decoded_part()
 
 
-def _ramp_codes(x: jax.Array, bounds: jax.Array) -> jax.Array:
-    """Ramp conversion: code = #boundaries crossed (ripple-counter value)."""
-    return jnp.sum((x[:, :, None] > bounds[None, None, :]),
-                   axis=-1).astype(jnp.int32)
+def _ramp_codes(x: jax.Array, bounds_ref) -> jax.Array:
+    """Ramp conversion: code = #boundaries crossed (ripple-counter value).
+
+    One (rows, cols) compare-and-count per boundary, each boundary a scalar
+    read from SMEM: the count is the same integer a (rows, cols, n_codes)
+    compare-and-sum gives, without a lane-padded 3-D temporary in VMEM.
+    """
+    codes = jnp.zeros(x.shape, jnp.int32)
+    for b in range(bounds_ref.shape[0]):
+        codes = codes + (x > bounds_ref[b]).astype(jnp.int32)
+    return codes
 
 
-def _lut_reconstruct(codes: jax.Array, levels: jax.Array,
-                     n_codes: int) -> jax.Array:
-    """LUT map-back as one-hot contraction (MXU-friendly; no VPU gather)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_codes), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32)
-    return jnp.sum(onehot * levels[None, None, :], axis=-1)
+def _lut_reconstruct(codes: jax.Array, levels_ref) -> jax.Array:
+    """LUT map-back: ``levels[code]`` by one select per code (no gather).
+
+    Codes outside [0, n_codes) (masked padded columns) map to 0.
+    """
+    out = jnp.zeros(codes.shape, jnp.float32)
+    for c in range(levels_ref.shape[0]):
+        out = jnp.where(codes == c, levels_ref[c], out)
+    return out
+
+
+def _lane_prefix_count(flags: jax.Array) -> jax.Array:
+    """Inclusive prefix count of 0/1 ``flags`` (rows, n) along the lanes.
+
+    Each 128-lane chunk is one contraction with an upper-triangular 0/1
+    matrix on the MXU, plus the running total of the chunks before it.
+    Every operand and partial sum is a small integer, exact in f32 (and in
+    bf16 operands), so the count equals ``cumsum`` bitwise; Mosaic has no
+    lane ``cumsum``.
+    """
+    n = flags.shape[-1]
+    w0 = min(n, 128)
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (w0, w0), 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, (w0, w0), 1)
+             ).astype(jnp.float32)
+    f = flags.astype(jnp.float32)
+    total = jnp.zeros((f.shape[0], 1), jnp.float32)
+    parts = []
+    for c0 in range(0, n, w0):
+        w = min(w0, n - c0)
+        chunk = f[:, c0:c0 + w]
+        parts.append(jax.lax.dot_general(
+            chunk, upper[:w, :w], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) + total)
+        total = total + jnp.sum(chunk, axis=-1, keepdims=True)
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+    return out.astype(jnp.int32)
 
 
 def _kwn_sweep(codes: jax.Array, k: int, n_codes: int, bounded: bool = False):
@@ -368,7 +483,7 @@ def _kwn_sweep(codes: jax.Array, k: int, n_codes: int, bounded: bool = False):
     def descend(level, carry):
         n_found, mask, steps = carry
         crossing = (codes == level) & (mask == 0)
-        order = jnp.cumsum(crossing.astype(jnp.int32), axis=-1)
+        order = _lane_prefix_count(crossing)
         admit = crossing & ((n_found + order) <= k)       # priority encoder
         mask = mask + admit.astype(jnp.int32)
         n_found = n_found + jnp.sum(admit.astype(jnp.int32), axis=-1,
@@ -550,7 +665,7 @@ def _seq_kwn_kernel(*refs, ratio, bm, bn, n_i, n_j, n_k, n_valid, k,
     def _head():
         seed, step, row_ids = _row_stream_ids(ctl_ref, ins.get("row_ctl"), t)
         mac = mac_ref[0]                                  # (bm, N) int-valued
-        codes = _ramp_codes(mac, bounds_ref[...][0])
+        codes = _ramp_codes(mac, bounds_ref)
         if ima_noise is not None:
             # The NLQ ramp sees integer-unit MACs; inject the Fig. 7 error
             # (INL + offset + Gaussian, in code LSBs) before the sweep, so
@@ -563,7 +678,7 @@ def _seq_kwn_kernel(*refs, ratio, bm, bn, n_i, n_j, n_k, n_valid, k,
                                      rows=row_ids)
         codes = _mask_padded_columns(codes, n_valid)
         maskf, steps = _kwn_sweep(codes, k, n_codes, bounded=gated)
-        recon = _lut_reconstruct(codes, levels_ref[...][0], n_codes)
+        recon = _lut_reconstruct(codes, levels_ref)
         # Winner drive: LUT value x per-column weight scale, losers exactly 0.
         drive = recon * scale_ref[...] * maskf * drive_gain
         nz = _lif_noise(noise_ref, v_ref.shape, seed, step, row0=row0,
@@ -609,7 +724,7 @@ def _seq_nld_kernel(*refs, ratio, bm, bn, n_i, n_j, n_k, n_codes,
     def _head():
         seed, step, row_ids = _row_stream_ids(ctl_ref, ins.get("row_ctl"), t)
         mac = mac_ref[0] * scale_ref[...]                 # (bm, J*N) float
-        codes = _ramp_codes(mac, bounds_ref[...][0])
+        codes = _ramp_codes(mac, bounds_ref)
         if ima_noise is not None:
             # NL-activation ramp: same conversion error, float-unit range.
             codes = _ima_noisy_codes(codes, mac, seed, step, row0=row0,
@@ -617,12 +732,16 @@ def _seq_nld_kernel(*refs, ratio, bm, bn, n_i, n_j, n_k, n_codes,
                                      logical_n=logical_n,
                                      ima_noise=ima_noise, n_codes=n_codes,
                                      rows=row_ids)
-        act = _lut_reconstruct(codes, levels_ref[...][0], n_codes)
+        act = _lut_reconstruct(codes, levels_ref)
         bm_rows = act.shape[0]
         n = v_ref.shape[-1]
-        act3 = act.reshape(bm_rows, n_branches, n)        # branch-major planes
         w_dend = w_dend_ref[...]                          # (J, N)
-        drive = jnp.sum(act3 * w_dend[None, :, :], axis=1) * drive_gain
+        # soma combine over the branch-major planes: one static slice per
+        # branch, summed in branch order from zero (the oracle's reduction)
+        drive = jnp.zeros((bm_rows, n), jnp.float32)
+        for jb in range(n_branches):
+            drive = drive + act[:, jb * n:(jb + 1) * n] * w_dend[jb:jb + 1]
+        drive = drive * drive_gain
         ones = jnp.ones((bm_rows, n), jnp.float32)        # dense LIF update
         v_new, spike, _ = _lif_update(
             v_ref[...], drive, ones, jnp.zeros((bm_rows, n), jnp.float32),
@@ -658,7 +777,7 @@ def fused_macro_seq(x: jax.Array, msb: jax.Array, lsb: jax.Array,
                     n_valid: int | None = None, ima_noise=None,
                     snl_amp: float = 0.0, logical_n: int | None = None,
                     mac_telemetry: bool = True, train_trace: bool = False,
-                    seed=0, step_offset=0, interpret: bool = True):
+                    seed=0, step_offset=0, interpret: bool):
     """A whole fused event sequence: T macro time steps in one kernel.
 
     x:           (T, M, K) int8 ternary inputs (time-major encoded events).
@@ -762,14 +881,14 @@ def fused_macro_seq(x: jax.Array, msb: jax.Array, lsb: jax.Array,
         pl.BlockSpec((1, bm, bk), lambda i, t, j, kk, *_: (t, i, kk)),  # x
         pl.BlockSpec((bk, bn), lambda i, t, j, kk, *_: (kk, j)),        # msb
         pl.BlockSpec((bk, bn), lambda i, t, j, kk, *_: (kk, j)),        # lsb
-        const_spec((1, n_codes - 1)),                                # bounds
-        const_spec((1, n_codes)),                                    # levels
+        _SMEM_SPEC,                                                  # bounds
+        _SMEM_SPEC,                                                  # levels
         const_spec((1, nc)),                                         # scale
         const_spec((1, 2)),                                          # ctl
     ]
     inputs = [x.astype(jnp.int8), msb.astype(jnp.int8), lsb.astype(jnp.int8),
-              boundaries.astype(jnp.float32).reshape(1, -1),
-              levels.astype(jnp.float32).reshape(1, -1),
+              boundaries.astype(jnp.float32).reshape(-1),
+              levels.astype(jnp.float32).reshape(-1),
               scale.astype(jnp.float32).reshape(1, -1),
               ctl]
     has_row_ctl = row_ctl is not None
@@ -844,6 +963,7 @@ def fused_macro_seq(x: jax.Array, msb: jax.Array, lsb: jax.Array,
                 out_specs=out_specs, scratch_shapes=scratch_shapes),
             out_shape=out_shape,
             interpret=interpret,
+            compiler_params=compiler_params(interpret),
         )(activity.reshape(-1).astype(jnp.int32), *inputs)
     else:
         outs = pl.pallas_call(
@@ -854,6 +974,7 @@ def fused_macro_seq(x: jax.Array, msb: jax.Array, lsb: jax.Array,
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
             interpret=interpret,
+            compiler_params=compiler_params(interpret),
         )(*inputs)
     outs = list(outs)
     mac = outs.pop(0) if mac_telemetry else None
@@ -891,6 +1012,24 @@ class LayerSpec(NamedTuple):
     def n_k(self) -> int:
         """Number of K tiles (occupancy words per (step, row-tile))."""
         return -(-self.k_dim // self.bk)
+
+
+def stack_vmem_bytes(specs, bm: int) -> int:
+    """Scoped-VMEM estimate of one stacked launch, in bytes, on a TPU.
+
+    Counted as ``TilePlan.vmem_bytes`` counts one layer: the layer-0 int8
+    event block and every layer's int8 weight planes and (bm, n) row
+    blocks, double-buffered at native tile padding, plus the head
+    temporaries of the widest layer (layers run one after another).
+    """
+    rows = _ceil_mult(bm, 8)
+    lanes = lambda w: _ceil_mult(w, 128)
+    blocks = _ceil_mult(bm, 32) * lanes(specs[0].k_dim)
+    for spec in specs:
+        blocks += 2 * _ceil_mult(spec.k_dim, 32) * lanes(spec.n)
+        blocks += 4 * rows * (4 * lanes(spec.n) + 3 * 128)
+    widest = max(lanes(spec.n) for spec in specs)
+    return 2 * blocks + HEAD_TEMPORARIES * 4 * rows * widest
 
 
 def _multi_seq_kwn_kernel(*refs, specs, ratio, bm, n_i, n_codes, beta,
@@ -975,13 +1114,13 @@ def _multi_seq_kwn_kernel(*refs, specs, ratio, bm, n_i, n_codes, beta,
                         n_occ = n_occ + (occ > 0).astype(jnp.int32)
             tiles.append(acc)
         mac = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, -1)
-        codes = _ramp_codes(mac, bounds_ref[...][0])
+        codes = _ramp_codes(mac, bounds_ref)
         if ima_noise is not None:
             codes = _ima_noisy_codes(codes, mac, seed, step, row0=row0,
                                      per_branch=spec.n, logical_n=spec.n,
                                      ima_noise=ima_noise, n_codes=n_codes)
         maskf, steps = _kwn_sweep(codes, spec.k, n_codes, bounded=gated)
-        recon = _lut_reconstruct(codes, levels_ref[...][0], n_codes)
+        recon = _lut_reconstruct(codes, levels_ref)
         drive = recon * scale_ref[...] * maskf * drive_gain
         nz = _lif_noise(noise_refs[li], (bm, spec.n), seed, step, row0=row0,
                         logical_n=spec.n, snl_amp=snl_amp, use_snl=use_snl)
@@ -1010,7 +1149,7 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
                           use_snl: bool = True, bm: int = DEFAULT_BM,
                           ima_noise=None, snl_amp: float = 0.0,
                           has_noise: bool = False, gated: bool = False,
-                          interpret: bool = True):
+                          interpret: bool):
     """L stacked KWN macro layers over a whole event sequence, one launch.
 
     x:       (T, M, K0) int8 ternary events (K0 padded to layer 0's K
@@ -1059,12 +1198,11 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
         assert msb.shape == (spec.k_dim, spec.n), (msb.shape, spec)
         in_specs += [const_spec((spec.k_dim, spec.n)),
                      const_spec((spec.k_dim, spec.n)),
-                     const_spec((1, n_codes - 1)),
-                     const_spec((1, n_codes)),
+                     _SMEM_SPEC, _SMEM_SPEC,
                      const_spec((1, spec.n))]
         inputs += [msb.astype(jnp.int8), lsb.astype(jnp.int8),
-                   bounds.astype(jnp.float32).reshape(1, -1),
-                   levels.astype(jnp.float32).reshape(1, -1),
+                   bounds.astype(jnp.float32).reshape(-1),
+                   levels.astype(jnp.float32).reshape(-1),
                    scale.astype(jnp.float32).reshape(1, -1)]
     for spec, v0 in zip(specs, v0s):
         assert v0.shape == (m, spec.n), (v0.shape, spec)
@@ -1108,6 +1246,7 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
                 in_specs=in_specs, out_specs=out_specs),
             out_shape=out_shape,
             interpret=interpret,
+            compiler_params=compiler_params(interpret),
         )(activity.reshape(-1).astype(jnp.int32), *inputs)
     else:
         outs = pl.pallas_call(
@@ -1117,6 +1256,7 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            compiler_params=compiler_params(interpret),
         )(*inputs)
     outs = list(outs)
     v_outs = tuple(outs[:n_layers])
@@ -1126,39 +1266,3 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
     occupancy = tuple(outs[3 * n_layers + 2:4 * n_layers + 2])
     return v_outs, spikes, mask, steps, counts, occupancy
 
-
-def fused_macro_step(x: jax.Array, msb: jax.Array, lsb: jax.Array,
-                     boundaries: jax.Array, levels: jax.Array,
-                     scale: jax.Array, v: jax.Array,
-                     noise: jax.Array | None = None,
-                     w_dend: jax.Array | None = None,
-                     activity: jax.Array | None = None, *,
-                     mode: str = "kwn", k: int = 12, ratio: float = 2.0,
-                     drive_gain: float = 1.0, beta: float = 0.9,
-                     v_th1: float = 1.0, v_th2: float = 0.6,
-                     v_reset: float = 0.0, v_lim: float = 8.0,
-                     use_snl: bool = True, bm: int = DEFAULT_BM,
-                     bk: int = DEFAULT_BK, bn: int | None = None,
-                     n_valid: int | None = None, ima_noise=None,
-                     snl_amp: float = 0.0, logical_n: int | None = None,
-                     mac_telemetry: bool = True,
-                     seed=0, step_offset=0, interpret: bool = True):
-    """One fused macro time step: the T=1 degenerate of ``fused_macro_seq``.
-
-    x (M, K), v/noise (M, N), activity (M/bm, K/bk); returns (mac (M, NC)
-    or None, v_out, spikes, mask, adc_steps (M, 1)) exactly like the PR 1
-    single-step kernel.  With ``ima_noise``, pass the scan index as
-    ``step_offset`` to reproduce the one-launch sequence stream exactly.
-    """
-    mac, v_out, spikes, mask, steps = fused_macro_seq(
-        x[None], msb, lsb, boundaries, levels, scale, v,
-        None if noise is None else noise[None], w_dend,
-        None if activity is None else activity[None],
-        mode=mode, k=k, ratio=ratio, drive_gain=drive_gain, beta=beta,
-        v_th1=v_th1, v_th2=v_th2, v_reset=v_reset, v_lim=v_lim,
-        use_snl=use_snl, bm=bm, bk=bk, bn=bn, n_valid=n_valid,
-        ima_noise=ima_noise, snl_amp=snl_amp, logical_n=logical_n,
-        mac_telemetry=mac_telemetry,
-        seed=seed, step_offset=step_offset, interpret=interpret)
-    return (None if mac is None else mac[0], v_out, spikes[0], mask[0],
-            steps[0])

@@ -69,6 +69,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import fused_macro as _fm
+
 
 def _seq_kwn_bwd_kernel(*refs, n_t, n_i, bn, ratio, drive_gain, beta, v_th1,
                         v_lim, kwn_relax, surrogate_beta, ste_lo, ste_hi,
@@ -89,8 +91,9 @@ def _seq_kwn_bwd_kernel(*refs, n_t, n_i, bn, ratio, drive_gain, beta, v_th1,
 
     i, ti, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     t_fwd = (n_t - 1) - ti
-    rows = pl.dslice(None)
-    col = pl.dslice(j * bn, bn)
+    single = dv0_ref.shape[-1] == bn          # one column tile: full slice
+    col = (slice(None),
+           slice(None) if single else pl.ds(pl.multiple_of(j * bn, bn), bn))
 
     @pl.when((i == 0) & (ti == 0) & (j == 0))
     def _zero_dw():
@@ -98,10 +101,10 @@ def _seq_kwn_bwd_kernel(*refs, n_t, n_i, bn, ratio, drive_gain, beta, v_th1,
 
     @pl.when(ti == 0)
     def _seed_carry():                       # g_v(T) = cotangent of v_out
-        pl.store(dv0_ref, (rows, col), pl.load(g_vfin_ref, (rows, col)))
+        dv0_ref[col] = g_vfin_ref[col]
 
     # --- elementwise cotangent chain (always runs: g_v must flow) --------
-    g_v = pl.load(dv0_ref, (rows, col))
+    g_v = dv0_ref[col]
     vt = vtrace_ref[0]                       # pre-reset saturated membrane
     m = mask_ref[0]
     spk = (vt >= v_th1).astype(jnp.float32)
@@ -110,8 +113,7 @@ def _seq_kwn_bwd_kernel(*refs, n_t, n_i, bn, ratio, drive_gain, beta, v_th1,
     g_vclip = g_v * (1.0 - spk) + g_spk_ref[0] * sg_spk
     inside = (jnp.abs(vt) < v_lim).astype(jnp.float32)    # rail cut
     g_v2 = g_vclip * inside                  # SNL add is grad-transparent
-    pl.store(dv0_ref, (rows, col),
-             g_v2 * (m * beta + (1.0 - m)))  # winners leak, losers hold
+    dv0_ref[col] = g_v2 * (m * beta + (1.0 - m))  # winners leak, losers hold
 
     # --- dW contraction (activity-gated: empty x_t blocks contribute 0) --
     def _contract():
@@ -130,17 +132,14 @@ def _seq_kwn_bwd_kernel(*refs, n_t, n_i, bn, ratio, drive_gain, beta, v_th1,
         g_mac = g_v2 * gate * scale_ref[...] * drive_gain * in_ramp
         part = jax.lax.dot_general(          # x_t^T @ g_mac: (K, bn)
             xf, g_mac, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # g_mac is not bf16-exact
             preferred_element_type=jnp.float32)
-        pl.store(dw_ref, (rows, col), pl.load(dw_ref, (rows, col)) + part)
+        dw_ref[col] = dw_ref[col] + part
 
-    if gated:
-        occ = occ_ref[t_fwd * n_i + i]
-
-        @pl.when(occ > 0)
-        def _gated_contract():
-            _contract()
-    else:
-        _contract()
+    # The dense launch runs the contraction under an always-true predicate,
+    # so both launches emit the same predicated dot: the f32 dW sums then
+    # accumulate in one order and gating stays bitwise-neutral.
+    pl.when(occ_ref[t_fwd * n_i + i] > 0 if gated else ti >= 0)(_contract)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -158,7 +157,7 @@ def fused_macro_seq_grad(x: jax.Array, scale: jax.Array, g_spk: jax.Array,
                          surrogate_beta: float = 4.0,
                          ste_lo: float = -24.5, ste_hi: float = 24.5,
                          bm: int = 128, bn: int | None = None,
-                         interpret: bool = True):
+                         interpret: bool):
     """The fused surrogate backward: padded operands, one launch.
 
     x:        (T, M, K) int8 ternary inputs (the forward's, padded).
@@ -238,6 +237,7 @@ def fused_macro_seq_grad(x: jax.Array, scale: jax.Array, g_spk: jax.Array,
                 out_specs=out_specs),
             out_shape=out_shape,
             interpret=interpret,
+        compiler_params=_fm.compiler_params(interpret),
         )(activity.reshape(-1).astype(jnp.int32), *inputs)
     return pl.pallas_call(
         kernel,
@@ -246,4 +246,5 @@ def fused_macro_seq_grad(x: jax.Array, scale: jax.Array, g_spk: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_fm.compiler_params(interpret),
     )(*inputs)

@@ -58,7 +58,7 @@ def _kwn_kernel(mac_ref, bounds_ref, mask_ref, steps_ref, *, k: int,
 
 @functools.partial(jax.jit, static_argnames=("k", "bm", "interpret"))
 def kwn_topk(mac: jax.Array, boundaries: jax.Array, k: int,
-             bm: int = DEFAULT_BM, interpret: bool = True):
+             bm: int = DEFAULT_BM, *, interpret: bool):
     """mac: (M, N) f32; boundaries: (n_codes-1,) -> (mask (M,N) f32,
     adc_steps (M,1) i32).  M must be a multiple of bm; N is the macro width.
     """

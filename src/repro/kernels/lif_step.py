@@ -47,7 +47,7 @@ def lif_step_fused(v: jax.Array, drive: jax.Array, mask: jax.Array,
                    noise: jax.Array, beta: float = 0.9, v_th1: float = 1.0,
                    v_th2: float = 0.6, v_reset: float = 0.0,
                    v_lim: float = 8.0, use_snl: bool = True,
-                   bm: int = DEFAULT_BM, interpret: bool = True):
+                   bm: int = DEFAULT_BM, *, interpret: bool):
     """All inputs (M, N) f32; returns (v_out, spikes), both (M, N) f32."""
     m, n = v.shape
     assert m % bm == 0, (m, bm)

@@ -40,7 +40,7 @@ def _nlq_kernel(x_ref, bounds_ref, levels_ref, code_ref, y_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def nlq_convert(x: jax.Array, boundaries: jax.Array, levels: jax.Array,
-                bm: int = DEFAULT_BM, interpret: bool = True):
+                bm: int = DEFAULT_BM, *, interpret: bool):
     """x: (M, N) f32 -> (codes (M,N) i32, reconstruction (M,N) f32)."""
     m, n = x.shape
     assert m % bm == 0, (m, bm)

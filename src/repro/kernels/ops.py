@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels: padding, dtype handling, and
-the interpret-mode switch (CPU validation vs TPU execution).
+the interpret-mode decision (CPU validation vs TPU execution).
 
-`INTERPRET` defaults to True because this container is CPU-only; on real TPU
-hardware set ``repro.kernels.ops.INTERPRET = False`` (or pass interpret=False).
+``interpret_mode()`` is the one place that decides how a kernel runs: Mosaic
+compiles it whenever the default backend is a TPU, and the Pallas
+interpreter runs it everywhere else.  Nothing falls back from the chip.
 """
 
 from __future__ import annotations
@@ -21,7 +22,15 @@ from repro.kernels import lif_step as _lif
 from repro.kernels import nlq_lut as _nlq
 from repro.kernels import ternary_mac as _tmac
 
-INTERPRET = True
+
+def interpret_mode() -> bool:
+    """True unless the default JAX backend is a TPU.
+
+    Every kernel wrapper here, and the plan cache's device key, asks this
+    at trace time; on a TPU the kernels compile with Mosaic, elsewhere they
+    run in the Pallas interpreter.
+    """
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):
@@ -51,7 +60,7 @@ def ternary_mac(x: jax.Array, msb: jax.Array, lsb: jax.Array,
     lsb_p, _ = _pad_to(lsb_p, 1, bn_)
     out = _tmac.ternary_mac(xm.astype(jnp.int8), msb_p.astype(jnp.int8),
                             lsb_p.astype(jnp.int8), bm=bm_, bn=bn_, bk=bk_,
-                            ratio=ratio, interpret=INTERPRET)
+                            ratio=ratio, interpret=interpret_mode())
     return out[:m0, :n0].reshape(*lead, n0)
 
 
@@ -66,7 +75,7 @@ def kwn_topk(mac: jax.Array, boundaries: jax.Array, k: int):
     bm = min(128, _ceil_mult(xm.shape[0], 8))
     xm, m0 = _pad_to(xm, 0, bm)
     mask, steps = _kwn.kwn_topk(xm, boundaries.astype(jnp.float32), k, bm=bm,
-                                interpret=INTERPRET)
+                                interpret=interpret_mode())
     return (mask[:m0].reshape(*lead, mac.shape[-1]),
             steps[:m0, 0].reshape(lead))
 
@@ -82,8 +91,8 @@ def lif_step(v, drive, mask, noise, **params):
     for a in flat:
         a, _ = _pad_to(a, 0, bm)
         padded.append(a)
-    v_out, spikes = _lif.lif_step_fused(*padded, bm=bm, interpret=INTERPRET,
-                                        **params)
+    v_out, spikes = _lif.lif_step_fused(*padded, bm=bm,
+                                        interpret=interpret_mode(), **params)
     return v_out[:m0].reshape(*lead, n), spikes[:m0].reshape(*lead, n)
 
 
@@ -263,7 +272,7 @@ def fused_macro_seq(x, msb, lsb, boundaries, levels, scale, v, noise=None,
         use_snl=use_snl, bm=plan.bm, bk=plan.bk, bn=plan.bn,
         n_valid=plan.n_valid, ima_noise=ima_noise, snl_amp=snl_amp,
         logical_n=n, mac_telemetry=mac_telemetry, train_trace=train_trace,
-        seed=seed, step_offset=step_offset, interpret=INTERPRET)
+        seed=seed, step_offset=step_offset, interpret=interpret_mode())
     mac, v_out, spikes, mask, steps = outs[:5]
     if mac is not None:
         mac = _unpad_cols(mac[:, :m0], n, plan.n_pad, n_branches)
@@ -374,9 +383,6 @@ def fused_macro_multi_seq(x, stack, vs, noises=None, *, ks,
     m0 = xm.shape[1]
     plan0 = _fused.plan_tiles(m0, kdim, widths[0], widths[0], t,
                               bm=bm, bk=tile_shapes[0][0])
-    xm = jnp.pad(xm, ((0, 0), (0, plan0.m_pad - m0),
-                      (0, plan0.k_pad - kdim)))
-    activity = fused_activity_map(xm, plan0) if gate else None
     specs = []
     for li in range(n_layers):
         k_dim = plan0.k_pad if li == 0 else widths[li - 1]
@@ -395,6 +401,23 @@ def fused_macro_multi_seq(x, stack, vs, noises=None, *, ks,
             bk=int(bk_l or min(k_dim, _fused.DEFAULT_BK)),
             bn=int(bn_l or min(widths[li], _fused.DEFAULT_BN))))
     specs = tuple(specs)
+    budget = _fused.plan_vmem_budget()
+    if budget is not None and _fused.stack_vmem_bytes(specs,
+                                                      plan0.bm) > budget:
+        # On a TPU the whole stack shares one launch's VMEM: halve the row
+        # tile (the layer widths are fixed) until it fits.
+        bm_fit = plan0.bm
+        while bm_fit > 8 and _fused.stack_vmem_bytes(specs, bm_fit) > budget:
+            bm_fit = max(8, bm_fit // 16 * 8)
+        if _fused.stack_vmem_bytes(specs, bm_fit) > budget:
+            raise ValueError(
+                f"stack {widths} needs more than the {budget}-byte VMEM "
+                f"limit even at 8 rows; it needs a layer-streaming schedule")
+        plan0 = _fused.plan_tiles(m0, kdim, widths[0], widths[0], t,
+                                  bm=bm_fit, bk=plan0.bk)
+    xm = jnp.pad(xm, ((0, 0), (0, plan0.m_pad - m0),
+                      (0, plan0.k_pad - kdim)))
+    activity = fused_activity_map(xm, plan0) if gate else None
     vs_p = tuple(jnp.pad(v.reshape(-1, w), ((0, plan0.m_pad - m0), (0, 0)))
                  for v, w in zip(vs, widths))
     noises_p = None
@@ -420,7 +443,8 @@ def fused_macro_multi_seq(x, stack, vs, noises=None, *, ks,
         specs=specs, ratio=ratio, drive_gain=drive_gain, beta=beta,
         v_th1=v_th1, v_th2=v_th2, v_reset=v_reset, v_lim=v_lim,
         use_snl=use_snl, bm=plan0.bm, ima_noise=ima_noise, snl_amp=snl_amp,
-        has_noise=noises is not None, gated=gate, interpret=INTERPRET)
+        has_noise=noises is not None, gated=gate,
+        interpret=interpret_mode())
     n_i = plan0.m_pad // plan0.bm
     return MultiSeqOut(
         v_outs=tuple(v[:m0].reshape(*lead, w)
@@ -567,7 +591,8 @@ def _seq_vjp_bwd(spec, res, cts):
         ratio=spec.ratio, drive_gain=spec.drive_gain, beta=spec.beta,
         v_th1=spec.v_th1, v_lim=spec.v_lim, kwn_relax=spec.kwn_relax,
         surrogate_beta=spec.surrogate_beta, ste_lo=spec.ste_lo,
-        ste_hi=spec.ste_hi, bm=plan.bm, bn=plan.bn, interpret=INTERPRET)
+        ste_hi=spec.ste_hi, bm=plan.bm, bn=plan.bn,
+        interpret=interpret_mode())
     dw = dw_p[:kdim, :n]
     dv0 = dv0_p[:m0, :n].reshape(*lead, n)
     return (dw, jnp.zeros_like(x), jnp.zeros_like(boundaries),
@@ -587,5 +612,5 @@ def nlq_convert(x, boundaries, levels):
     xm, m0 = _pad_to(xm, 0, bm)
     codes, y = _nlq.nlq_convert(xm, boundaries.astype(jnp.float32),
                                 levels.astype(jnp.float32), bm=bm,
-                                interpret=INTERPRET)
+                                interpret=interpret_mode())
     return codes[:m0].reshape(*lead, n), y[:m0].reshape(*lead, n)

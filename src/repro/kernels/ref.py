@@ -124,7 +124,11 @@ def fused_head_ref(mac, boundaries, levels, scale, v, noise=None,
                                             step, ima_noise, cb.n_codes)
         act = ima_lib.ima_reconstruct(codes, cb)
         act3 = act.reshape(act.shape[:-1] + (n_branches, n))
-        drive = jnp.sum(act3 * w_dend, axis=-2) * drive_gain
+        # soma combine summed in branch order from zero, as the kernel does
+        drive = jnp.zeros(act3.shape[:-2] + (n,), jnp.float32)
+        for jb in range(n_branches):
+            drive = drive + act3[..., jb, :] * w_dend[jb]
+        drive = drive * drive_gain
         mask = jnp.ones(v.shape, jnp.float32)
         steps = jnp.full(v.shape[:-1] + (1,), cb.n_codes - 1, jnp.int32)
         use_snl = False

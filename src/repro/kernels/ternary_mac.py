@@ -48,7 +48,7 @@ def _ternary_mac_kernel(x_ref, msb_ref, lsb_ref, o_ref, *, ratio: float,
 def ternary_mac(x: jax.Array, msb: jax.Array, lsb: jax.Array,
                 bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                 bk: int = DEFAULT_BK, ratio: float = 2.0,
-                interpret: bool = True) -> jax.Array:
+                *, interpret: bool) -> jax.Array:
     """x: (M, K) int8 ternary; msb/lsb: (K, N) int8 ternary -> (M, N) f32.
 
     Shapes must be multiples of the block sizes (``ops.py`` pads).

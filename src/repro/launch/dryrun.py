@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x mesh)
 cell with ShapeDtypeStruct stand-ins (no allocation), record memory_analysis /
 cost_analysis / collective wire bytes for the roofline.
@@ -15,6 +12,7 @@ decode_32k / long_500k lower serve_step (one token against the full cache).
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -234,4 +232,6 @@ def main():
 
 
 if __name__ == "__main__":
+    # 512 host devices for the production meshes, before the backend starts
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

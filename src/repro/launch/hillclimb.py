@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """§Perf hillclimbing driver: hypothesis -> change -> re-lower/compile ->
 measure -> confirmed/refuted, per EXPERIMENTS.md §Perf.
 
@@ -21,6 +18,7 @@ artifact's memory/HLO-collective cross-checks.
 import argparse
 import dataclasses
 import json
+import os
 
 
 from repro.configs import get_config
@@ -212,6 +210,11 @@ def run_cell(cell: str, compile_variants: bool = True):
 
 
 def main():
+    # 512 host devices for the production mesh: set here, never at import,
+    # so a process that imports this module for ``prior_guided_search``
+    # (the tile tuner, on the chip) keeps its own device set.
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", choices=list(LADDERS) + ["all"], default="all")
     ap.add_argument("--no-compile", action="store_true")

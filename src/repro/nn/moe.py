@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.nn import layers
 from repro.nn.module import ParamSpec
 
@@ -186,8 +185,8 @@ def moe_a2a(p: dict, x: jax.Array, *, k: int, mesh: Mesh,
     in_specs = (P(), P(expert_axis), P(expert_axis), P(expert_axis),
                 P(*tspec, seq_spec, None))
     out_specs = (P(*tspec, seq_spec, None), P())
-    fn = compat.shard_map(local_fn, mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["w_in"], p["w_gate"], p["w_out"], x)
 
 
@@ -290,8 +289,8 @@ def moe_2d(p: dict, x: jax.Array, *, k: int, mesh: Mesh,
                 P(row_spec, tp_axis, None),     # w_out (E, F, D)
                 P(row_spec, seq_spec, None))
     out_specs = (P(row_spec, seq_spec, None), P())
-    fn = compat.shard_map(local_fn, mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["w_in"], p["w_gate"], p["w_out"], x)
 
 
@@ -341,8 +340,8 @@ def moe_dense_ep_2d(p: dict, x: jax.Array, *, k: int, mesh: Mesh,
     in_specs = (P(), P(row_spec, None, tp_axis), P(row_spec, None, tp_axis),
                 P(row_spec, tp_axis, None), P(row_spec, None, None))
     out_specs = (P(row_spec, None, None), P())
-    fn = compat.shard_map(local_fn, mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["w_in"], p["w_gate"], p["w_out"], x)
 
 
@@ -381,6 +380,6 @@ def moe_dense_ep(p: dict, x: jax.Array, *, k: int, mesh: Mesh,
     in_specs = (P(), P(expert_axis), P(expert_axis), P(expert_axis),
                 P(*tspec, None, None))
     out_specs = (P(*tspec, None, None), P())
-    fn = compat.shard_map(local_fn, mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["w_in"], p["w_gate"], p["w_out"], x)

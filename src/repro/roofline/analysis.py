@@ -210,8 +210,7 @@ def model_flops(n_active_params: int, n_tokens: int, kind: str,
 
 def from_compiled(compiled, lowered_text: str | None = None,
                   model_flops_per_device: float = 0.0) -> Roofline:
-    from repro import compat
-    ca = compat.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     hlo = lowered_text or compiled.as_text()
     coll = collective_bytes(hlo)
     return Roofline(

@@ -112,7 +112,7 @@ def shape_key(m: int, k_dim: int, nc: int, n: int, t: int) -> str:
 
 
 def device_kind() -> str:
-    """Cache-key device identity: JAX device kind + the interpret switch.
+    """Cache-key device identity: JAX device kind + the interpret mode.
 
     Interpret-mode timings (CPU validation) and real-hardware timings live
     in different performance regimes, so an interpret-tuned plan must never
@@ -123,7 +123,7 @@ def device_kind() -> str:
     import jax
     kind = jax.devices()[0].device_kind.lower()
     from repro.kernels import ops as _ops   # late: avoid import cycles
-    return f"{kind}:interpret" if _ops.INTERPRET else kind
+    return f"{kind}:interpret" if _ops.interpret_mode() else kind
 
 
 def default_path() -> str:

@@ -8,10 +8,9 @@ Sharding-rule unit tests run in-process.
 Triage note (PR 2): the long-standing failure here was NOT a numerical
 tolerance issue — the subprocess crashed at mesh construction on jax
 versions without ``jax.sharding.AxisType`` / ``jax.shard_map`` before any
-comparison ran.  With the ``repro.compat`` shims, all four EP paths match
-the dense reference within the original 2e-4 tolerances on both jax
-generations; no tolerance was loosened and no accumulation-order change was
-needed.
+comparison ran.  On ``jax.shard_map`` and ``AxisType`` meshes, all four
+EP paths match the dense reference within the original 2e-4 tolerances; no
+tolerance was loosened and no accumulation-order change was needed.
 """
 
 import os
@@ -32,10 +31,10 @@ _MOE_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
     from repro.nn import moe, module
 
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     E, D, F, K = 8, 16, 32, 2
     B, S = 4, 8
     key = jax.random.PRNGKey(0)

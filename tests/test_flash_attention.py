@@ -8,6 +8,7 @@ import pytest
 
 from repro.kernels.flash_attention import (causal_flops_saving,
                                            flash_attention_fwd)
+from repro.kernels.ops import interpret_mode
 
 
 def _naive(q, k, v, causal):
@@ -31,7 +32,8 @@ def test_matches_naive(s, bq, bk, causal):
     key = jax.random.PRNGKey(s + bq)
     q, k, v = [jax.random.normal(jax.random.fold_in(key, i), (2, s, 16))
                for i in range(3)]
-    out = flash_attention_fwd(q, k, v, causal=causal, bq=bq, bk=bk)
+    out = flash_attention_fwd(q, k, v, causal=causal, bq=bq, bk=bk,
+                              interpret=interpret_mode())
     ref = _naive(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -41,7 +43,8 @@ def test_bf16_inputs():
     key = jax.random.PRNGKey(9)
     q, k, v = [jax.random.normal(jax.random.fold_in(key, i), (2, 128, 32)
                                  ).astype(jnp.bfloat16) for i in range(3)]
-    out = flash_attention_fwd(q, k, v, causal=True, bq=64, bk=64)
+    out = flash_attention_fwd(q, k, v, causal=True, bq=64, bk=64,
+                              interpret=interpret_mode())
     ref = _naive(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                np.asarray(ref), rtol=2e-2, atol=2e-2)
@@ -58,7 +61,8 @@ def test_numerical_stability_large_logits():
     key = jax.random.PRNGKey(11)
     q, k, v = [jax.random.normal(jax.random.fold_in(key, i), (1, 128, 16)) * 30
                for i in range(3)]
-    out = flash_attention_fwd(q, k, v, causal=True, bq=32, bk=32)
+    out = flash_attention_fwd(q, k, v, causal=True, bq=32, bk=32,
+                              interpret=interpret_mode())
     assert bool(jnp.all(jnp.isfinite(out)))
     ref = _naive(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

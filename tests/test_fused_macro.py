@@ -212,7 +212,7 @@ class TestTilePlanner:
         assert plan.k_pad % plan.bk == 0
         assert plan.nc_pad % plan.bn == 0
         assert plan.n_valid == 256
-        assert plan.vmem_resident_bytes > 0
+        assert plan.vmem_bytes > 0
 
     def test_nld_padding_is_branch_aligned(self):
         from repro.kernels import fused_macro

@@ -4,7 +4,7 @@ sequential composition of the stages (subprocess with 2 simulated devices).
 Triage note (PR 2): the long-standing failure here was NOT a numerical
 tolerance issue — the subprocess died on ``jax.sharding.AxisType``
 (missing on the container jax) and on the then-missing
-``repro.dist.pipeline`` module.  With ``repro.compat.make_mesh`` and the
+``repro.dist.pipeline`` module.  With ``AxisType`` meshes and the
 GPipe implementation in place, the pipeline matches the sequential
 reference within the original 1e-5 tolerances; nothing numerical changed.
 """
@@ -21,10 +21,10 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro import compat
     from repro.dist.pipeline import gpipe, bubble_fraction
 
-    mesh = compat.make_mesh((2,), ("pod",))
+    mesh = jax.make_mesh((2,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     D = 8
     key = jax.random.PRNGKey(0)
     w = jax.random.normal(key, (2, D, D)) / jnp.sqrt(D)   # one W per stage

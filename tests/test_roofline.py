@@ -24,9 +24,9 @@ class TestCollectiveParser:
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
             import jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
-            from repro import compat
             from repro.roofline import analysis
-            mesh = compat.make_mesh((4,), ("model",))
+            mesh = jax.make_mesh((4,), ("model",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             w = jax.ShapeDtypeStruct((512, 256), jnp.float32)
             x = jax.ShapeDtypeStruct((8, 512), jnp.float32)
             f = lambda w, x: jnp.sum(x @ w)

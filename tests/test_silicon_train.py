@@ -292,10 +292,16 @@ def test_finetune_recovers_noisy_accuracy():
     """The reduced Fig. 8 robustness experiment (the acceptance criterion):
     software pre-train, then silicon fine-tune with noise-aware QAT; the
     fine-tuned model's *noisy* fused accuracy must be at least the
-    software-trained baseline's on both event-dataset stand-ins."""
+    software-trained baseline's on both event-dataset stand-ins.
+
+    The fine-tune must move the model: at the silicon forward's
+    near-uniform starting loss (~2.25), 60 steps at lr 0.01-0.04 leave the
+    loss within 0.03 of where it started and the noisy accuracy within
+    eval noise of the baseline, on either side.  100 steps at lr 0.3 lift
+    it by 0.2-0.3 on both datasets, for fine-tune seeds 5, 6 and 7."""
     from repro.data import events as ev_lib
     nm = ima_lib.IMANoiseModel()
-    for name, k, ft_lr in (("nmnist", 3, 0.01), ("dvs_gesture", 12, 0.02)):
+    for name, k in (("nmnist", 3), ("dvs_gesture", 12)):
         dcfg = ev_lib.DATASETS[name]
         ds = ev_lib.EventDataset(dcfg)
         cfg = snn.SNNConfig(n_in=dcfg.n_in, n_steps=dcfg.n_steps,
@@ -303,7 +309,7 @@ def test_finetune_recovers_noisy_accuracy():
         p, _ = snn.train(cfg, ds, n_steps=150, batch=64)
         base_noisy, _ = snn.evaluate(p, cfg, ds, jax.random.PRNGKey(1),
                                      n_batches=8, noise=nm, fused=True)
-        p_ft, losses = snn.train(cfg, ds, n_steps=60, batch=64, lr=ft_lr,
+        p_ft, losses = snn.train(cfg, ds, n_steps=100, batch=64, lr=0.3,
                                  seed=5, silicon=True, noise=nm, params=p)
         ft_noisy, _ = snn.evaluate(p_ft, cfg, ds, jax.random.PRNGKey(1),
                                    n_batches=8, noise=nm, fused=True)

@@ -153,8 +153,7 @@ class TestRooflineModelValidation:
             return jax.value_and_grad(lambda pp: lm.loss_fn(pp, bt, cfg)[0])(p)
 
         compiled = jax.jit(train_like).lower(params, batch).compile()
-        from repro import compat
-        ca = compat.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         hlo_flops = float(ca["flops"])
 
         fwd_i, _ = flops_model.fwd_flops_per_token(cfg, "train", s,

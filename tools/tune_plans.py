@@ -71,7 +71,10 @@ def main(argv=None) -> int:
         from repro.obs import trace as obs_trace
         obs_trace.set_tracer(obs_trace.Tracer(enabled=True))
 
+    from repro import compile_cache
     from repro.tune import autotune, cache
+
+    compile_cache.enable()
 
     if args.smoke:
         cells = (autotune.TuneCell(16, 128, 128, 128, 4, 0.05),)
