@@ -3,9 +3,10 @@
 Design constraints, in priority order:
 
 1. **Zero-cost when disabled.**  The serving engine calls into the
-   tracer on every tick phase and every slot transition; the <2 %
-   bench-overhead gate (tools/check_bench.py) only holds if the
-   disabled path allocates nothing.  ``span()`` on a disabled tracer
+   tracer on every request, tick phase and slot transition, and the
+   chip benchmark times serving with the tracer off (``bench/run.py
+   --trace 0``); those numbers hold only if the disabled path
+   allocates nothing.  ``span()`` on a disabled tracer
    returns a module-level singleton null context manager; ``begin()``
    returns ``None`` and ``end(None)`` is a single attribute check.
 2. **Monotonic clocks.**  All timestamps come from

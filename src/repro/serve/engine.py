@@ -41,12 +41,10 @@ ROUND_MS_SAMPLE_WINDOW = 512      # recent rounds kept for exact quantiles
 
 # Fixed bucket edges for the per-request metric histograms.  ADC sweep
 # depth is bounded by the ramp (2**code_bits - 1 = 15 for the paper's
-# 4-bit code); ratios live in [0, 1]; modeled pJ/SOP lands near the
-# paper's 0.8 headline.
+# 4-bit code); ratios live in [0, 1].
 ADC_STEP_BUCKETS = (1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 8.0, 10.0, 12.0,
                     14.0, 15.0)
 RATIO_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0)
-PJ_PER_SOP_BUCKETS = (0.25, 0.5, 0.75, 1.0, 1.25, 1.5, 2.0, 3.0, 5.0)
 
 
 def build_serve_step(cfg: lm.LMConfig, mesh=None, *, temperature: float = 0.0):
@@ -99,6 +97,7 @@ class EventRequest:
     skipped_block_ratio: float | None = None  # batch activity-plan skip rate
     key: Any = None                  # per-request PRNG key (continuous path)
     latency_ms: float | None = None  # submit -> eviction wall time
+    queue_ms: float | None = None    # submit -> first admission wall time
     sops: float | None = None        # measured synaptic ops per time step
     priority: int = 0                # scheduler priority (higher preempts)
     deadline_ms: float | None = None  # SLO deadline, wall ms from submit
@@ -270,12 +269,12 @@ class SNNEventEngine:
             s: m.counter("terminal_total", state=s)
             for s in sorted(lifecycle.TERMINAL_STATES)}
         self._m_latency = m.histogram("request_latency_ms")
+        self._m_queue_wait = m.histogram("queue_wait_ms")
         self._m_adc = m.histogram("request_adc_steps",
                                   buckets=ADC_STEP_BUCKETS)
         self._m_skip = m.histogram("request_skipped_block_ratio",
                                    buckets=RATIO_BUCKETS)
-        self._m_pj = m.histogram("request_pj_per_sop",
-                                 buckets=PJ_PER_SOP_BUCKETS)
+        self._m_pulls = m.counter("host_pulls_total")
         # continuous-path slot table (host shadows of the device state)
         self._state = (snn_lib.silicon_stream_init(cfg, batch_slots)
                        if continuous else None)
@@ -311,17 +310,25 @@ class SNNEventEngine:
             self._m_adc.observe(req.adc_steps)
         if req.skipped_block_ratio is not None:
             self._m_skip.observe(req.skipped_block_ratio)
-        if req.adc_steps is not None and self.cfg.mode == "kwn" \
-                and req.density:
-            # modeled pJ/SOP for *this* request: the calibrated component
-            # model evaluated at the request's measured early-stop depth,
-            # with its measured event density standing in for the
-            # dataset spike rate (the engine does not know the dataset;
-            # energy_report recomputes with the calibrated rate)
-            bd = energy_lib.kwn_step_energy(self.cfg.k, req.density,
-                                            adc_steps=req.adc_steps)
-            self._m_pj.observe(
-                bd.total / energy_lib.sops_per_step(req.density))
+
+    def _observe_admitted(self, req: EventRequest, now: float) -> None:
+        """Stamp the submit -> first admission wait (``queue_ms``)."""
+        if req.queue_ms is None and req._t_submit is not None:
+            req.queue_ms = (now - req._t_submit) * 1e3
+            self._m_queue_wait.observe(req.queue_ms)
+
+    def _to_host(self, x) -> np.ndarray:
+        """Pull one device value to the host.
+
+        Every device->host read of a result or a seed goes through here:
+        it counts in ``host_pulls_total`` whether tracing is on or off,
+        and it is the one read allowed under
+        ``jax.transfer_guard_device_to_host("disallow")``, so serving
+        under that guard proves the counter misses no pull.
+        """
+        self._m_pulls.inc()
+        with jax.transfer_guard_device_to_host("allow"):
+            return np.asarray(x)
 
     def submit(self, req: EventRequest) -> EventRequest:
         """Enqueue a request; returns it with ``state`` set.
@@ -334,13 +341,25 @@ class SNNEventEngine:
         lowest-priority / newest request instead: the shed request (which
         may be ``req`` itself) gets the terminal ``REJECTED`` state and is
         recorded in ``self.rejected``.
+
+        Traced as an ``enqueue`` span (track ``admission``) holding its
+        ``validate`` and ``density`` phases.
         """
-        if self.validate:
-            lifecycle.validate_events(req.events, self.cfg.n_in)
-        if req.density is None:
-            # host-side numpy: no device dispatch/sync on the submit path
-            ev = np.asarray(req.events)
-            req.density = float(np.count_nonzero(ev)) / ev.size
+        tr = self.tracer
+        with tr.span("enqueue", track="admission",
+                     args={"uid": req.uid} if tr.enabled else None):
+            if self.validate:
+                with tr.span("validate", track="admission"):
+                    lifecycle.validate_events(req.events, self.cfg.n_in)
+            if req.density is None:
+                # host-side numpy: no device dispatch/sync on the submit path
+                with tr.span("density", track="admission"):
+                    ev = np.asarray(req.events)
+                    req.density = float(np.count_nonzero(ev)) / ev.size
+            return self._enqueue(req)
+
+    def _enqueue(self, req: EventRequest) -> EventRequest:
+        """Queue a validated request, shedding one if the queue is full."""
         req._order = self._submitted
         req._t_submit = time.perf_counter()
         req.state = lifecycle.QUEUED
@@ -374,36 +393,53 @@ class SNNEventEngine:
     # ------------------------------------------------------------------
 
     def _run_batch(self, reqs: list[EventRequest]) -> list[EventRequest]:
+        """One whole-sequence batch: a ``legacy_batch`` span holding its
+        ``stage``, ``launch``, ``wait`` and ``readout`` phases."""
         tr = self.tracer
         batch_span = tr.begin("legacy_batch", track="scheduler")
+        pulls0 = self._m_pulls.value
+        now = time.perf_counter()
+        for req in reqs:
+            self._observe_admitted(req, now)
+        stage = tr.begin("stage", track="scheduler")
         ev = jnp.stack([jnp.asarray(r.events, jnp.float32) for r in reqs])
         pad = self.b - ev.shape[0]
         if pad:
             ev = jnp.concatenate(
                 [ev, jnp.zeros((pad,) + ev.shape[1:], ev.dtype)])
-        self._key, sub = jax.random.split(self._key)
-        fwd = _legacy_forward(self.cfg, self._fused, self.noise)
-        logits, tele = fwd(self.params, ev, sub)
-        preds = jnp.argmax(logits, axis=-1)
-        skipped = tele.get("skipped_block_ratio")
+        if stage is not None:
+            tr.end(stage, args={"bytes": ev.nbytes})
+        with tr.span("launch", track="scheduler"):
+            self._key, sub = jax.random.split(self._key)
+            fwd = _legacy_forward(self.cfg, self._fused, self.noise)
+            logits, tele = fwd(self.params, ev, sub)
+        with tr.span("wait", track="scheduler"):
+            jax.block_until_ready((logits, tele))
         t_done = time.perf_counter()
-        for i, req in enumerate(reqs):
-            req.logits = logits[i]
-            req.pred = int(preds[i])
-            req.adc_steps = float(tele["adc_steps"][i])
-            req.sops = float(tele["sops"][i])
-            if skipped is not None:
-                req.skipped_block_ratio = float(skipped[i])
-            if req._t_submit is not None:
-                req.latency_ms = (t_done - req._t_submit) * 1e3
-            req.state = lifecycle.COMPLETED
-            if req.deadline_ms is not None and req.latency_ms is not None:
-                req.deadline_missed = req.latency_ms > req.deadline_ms
-            self.completed.append(req)
-            self._record_terminal(req)
-            self._observe_completed(req)
-        tr.end(batch_span,
-               args={"batch": len(reqs)} if batch_span is not None else None)
+        with tr.span("readout", track="scheduler"):
+            preds = jnp.argmax(logits, axis=-1)
+            skipped = tele.get("skipped_block_ratio")
+            for i, req in enumerate(reqs):
+                req.logits = logits[i]
+                req.pred = int(self._to_host(preds[i]))
+                req.adc_steps = float(self._to_host(tele["adc_steps"][i]))
+                req.sops = float(self._to_host(tele["sops"][i]))
+                if skipped is not None:
+                    req.skipped_block_ratio = float(
+                        self._to_host(skipped[i]))
+                if req._t_submit is not None:
+                    req.latency_ms = (t_done - req._t_submit) * 1e3
+                req.state = lifecycle.COMPLETED
+                if req.deadline_ms is not None and \
+                        req.latency_ms is not None:
+                    req.deadline_missed = req.latency_ms > req.deadline_ms
+                self.completed.append(req)
+                self._record_terminal(req)
+                self._observe_completed(req)
+        if batch_span is not None:
+            tr.end(batch_span, args={
+                "batch": len(reqs), "requests": len(reqs),
+                "pulls": self._m_pulls.value - pulls0})
         return reqs
 
     def _take_bucket(self) -> list[EventRequest]:
@@ -451,7 +487,7 @@ class SNNEventEngine:
             req.key = jax.random.fold_in(self._base_key, req._order)
         if self.noise is None:
             return 0              # clean serving never reads the seed word
-        return int(snn_lib._noise_seed(req.key))
+        return int(self._to_host(snn_lib._noise_seed(req.key)))
 
     # --- deadline bookkeeping -----------------------------------------
 
@@ -520,17 +556,18 @@ class SNNEventEngine:
 
     # --- admission ----------------------------------------------------
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Fill free slots from the queue; returns how many were admitted."""
         free = [i for i, r in enumerate(self._slot_req) if r is None]
         if not free or not self.pending:
-            return
+            return 0
         # backoff gate: a freshly preempted request sits out its
         # exponential-backoff window (measured in scheduling ticks, which
         # advance even on idle rounds, so the window always expires)
         eligible = [r for r in self.pending
                     if r._not_before <= self._rounds_total]
         if not eligible:
-            return
+            return 0
         scheduled = any(r.priority != 0 or r.deadline_ms is not None
                         or r._ckpt is not None for r in eligible)
         if scheduled:
@@ -556,18 +593,21 @@ class SNNEventEngine:
         self.pending = [r for r in self.pending if id(r) not in taken]
         mask = np.zeros(self.b, bool)
         tr = self.tracer
+        now = time.perf_counter()
         for slot, req in zip(free, chosen):
             self._slot_req[slot] = req
             self._slot_admit_round[slot] = self._rounds_total
             req.state = lifecycle.RUNNING
             self._m_admitted.inc()
+            self._observe_admitted(req, now)
             if tr.enabled:
                 # residency span: one lane per slot, open until the
                 # request leaves the slot (evict or preempt)
                 req._span = tr.begin(
                     f"req{req.uid}", track=f"slot{slot:02d}",
                     args={"uid": req.uid, "priority": req.priority,
-                          "resumed": req._ckpt is not None})
+                          "resumed": req._ckpt is not None,
+                          "queue_ms": req.queue_ms})
             if req._ckpt is not None:
                 if req._t_preempt_out is not None:
                     # checkpoint dwell: wall time spent off-device since
@@ -595,6 +635,7 @@ class SNNEventEngine:
         if mask.any():
             self._state = snn_lib.silicon_stream_admit(
                 self._state, mask, self._slot_len, self._slot_seed)
+        return len(chosen)
 
     # --- preemption ---------------------------------------------------
 
@@ -696,10 +737,14 @@ class SNNEventEngine:
         ``r`` defaults to the regular ``round_steps`` cadence; smaller
         values are the *partial rounds* the preemption path uses to stop a
         stream at a non-round-aligned offset (each distinct ``r`` compiles
-        one jit entry, bounded by ``round_steps``).
+        one jit entry, bounded by ``round_steps``).  Traced as a ``round``
+        span holding its ``stage`` (host buffer and transfer) and
+        ``launch`` phases.
         """
         r = self.round_steps if r is None else r
-        span = self.tracer.begin("round", track="scheduler")
+        tr = self.tracer
+        span = tr.begin("round", track="scheduler")
+        stage = tr.begin("stage", track="scheduler")
         ev = np.zeros((r, self.b, self.cfg.n_in), np.float32)
         for i, req in enumerate(self._slot_req):
             if req is None:
@@ -708,51 +753,74 @@ class SNNEventEngine:
                                np.float32)[self._slot_done[i]:
                                            self._slot_done[i] + r]
             ev[:chunk.shape[0], i, :] = chunk
-        self._state = snn_lib.forward_silicon_stream(
-            self.params, jnp.asarray(ev), self.cfg, self._state,
-            noise=self.noise)
+        events = jnp.asarray(ev)
+        if stage is not None:
+            tr.end(stage, args={"bytes": ev.nbytes})
+        with tr.span("launch", track="scheduler"):
+            self._state = snn_lib.forward_silicon_stream(
+                self.params, events, self.cfg, self._state,
+                noise=self.noise)
         self._slot_done = np.minimum(self._slot_done + r, self._slot_len)
         self._m_rounds.inc()
         if span is not None:
-            self.tracer.end(span, args={"steps": r, "active": self.active})
+            tr.end(span, args={"steps": r, "active": self.active})
 
     def _evict(self) -> list[EventRequest]:
+        """Retire every slot whose stream has ended: an ``evict`` span
+        holding, when a request finishes, its ``wait`` (the device
+        finishing the round) and ``readout`` phases."""
+        tr = self.tracer
+        span = tr.begin("evict", track="scheduler")
+        pulls0 = self._m_pulls.value
+        finished = [i for i, req in enumerate(self._slot_req)
+                    if req is not None
+                    and self._slot_done[i] >= self._slot_len[i]]
         out: list[EventRequest] = []
-        w_out = self.params["w_out"]
-        for i, req in enumerate(self._slot_req):
-            if req is None or self._slot_done[i] < self._slot_len[i]:
-                continue
-            length = float(self._slot_len[i])
-            # batch-1 shaped readout: bitwise-matches the one-shot path
-            logits = (self._state.counts[i][None] / length) @ w_out
-            req.logits = logits[0]
-            req.pred = int(jnp.argmax(logits, axis=-1)[0])
-            # f32 division: matches the one-shot telemetry normalization bit
-            # for bit (tele / t_steps runs in f32 inside the jitted forward)
-            lf = np.float32(length)
-            req.adc_steps = float(np.float32(self._state.adc[i]) / lf)
-            req.sops = float(np.float32(self._state.sops[i]) / lf)
-            req.skipped_block_ratio = float(
-                np.float32(self._state.skip_acc[i]) / lf)
-            if req._t_submit is not None:
-                req.latency_ms = (time.perf_counter() -
-                                  req._t_submit) * 1e3
-            req.state = lifecycle.COMPLETED
-            if req.deadline_ms is not None and req.latency_ms is not None:
-                req.deadline_missed = req.latency_ms > req.deadline_ms
-            self._slot_req[i] = None
-            self.completed.append(req)
-            self._m_evicted.inc()
-            self._record_terminal(req)
-            self._observe_completed(req)
-            if req._span is not None:
-                self.tracer.end(req._span,
-                                args={"outcome": "completed",
-                                      "latency_ms": req.latency_ms,
-                                      "preemptions": req.preemptions})
-                req._span = None
-            out.append(req)
+        if finished:
+            # the first pull would block here anyway; waiting first keeps
+            # device time out of the readout
+            with tr.span("wait", track="scheduler"):
+                jax.block_until_ready(self._state)
+            with tr.span("readout", track="scheduler"):
+                out = [self._complete_slot(i) for i in finished]
+        if span is not None:
+            tr.end(span, args={"requests": len(out),
+                               "pulls": self._m_pulls.value - pulls0})
         return out
+
+    def _complete_slot(self, i: int) -> EventRequest:
+        """Read slot ``i``'s answer back and retire its request."""
+        req = self._slot_req[i]
+        length = float(self._slot_len[i])
+        # batch-1 shaped readout: bitwise-matches the one-shot path
+        logits = (self._state.counts[i][None] / length) @ self.params["w_out"]
+        req.logits = logits[0]
+        req.pred = int(self._to_host(jnp.argmax(logits, axis=-1)[0]))
+        # f32 division: matches the one-shot telemetry normalization bit
+        # for bit (tele / t_steps runs in f32 inside the jitted forward)
+        lf = np.float32(length)
+        req.adc_steps = float(
+            np.float32(self._to_host(self._state.adc[i])) / lf)
+        req.sops = float(np.float32(self._to_host(self._state.sops[i])) / lf)
+        req.skipped_block_ratio = float(
+            np.float32(self._to_host(self._state.skip_acc[i])) / lf)
+        if req._t_submit is not None:
+            req.latency_ms = (time.perf_counter() - req._t_submit) * 1e3
+        req.state = lifecycle.COMPLETED
+        if req.deadline_ms is not None and req.latency_ms is not None:
+            req.deadline_missed = req.latency_ms > req.deadline_ms
+        self._slot_req[i] = None
+        self.completed.append(req)
+        self._m_evicted.inc()
+        self._record_terminal(req)
+        self._observe_completed(req)
+        if req._span is not None:
+            self.tracer.end(req._span,
+                            args={"outcome": "completed",
+                                  "latency_ms": req.latency_ms,
+                                  "preemptions": req.preemptions})
+            req._span = None
+        return req
 
     @property
     def active(self) -> int:
@@ -808,17 +876,18 @@ class SNNEventEngine:
             self._maybe_preempt()
             tr.end(h)
             h = tr.begin("admit", track="scheduler")
-            self._admit()
-            tr.end(h)
+            pulls0 = self._m_pulls.value
+            admitted = self._admit()
+            if h is not None:
+                tr.end(h, args={"admitted": admitted,
+                                "pulls": self._m_pulls.value - pulls0})
             self._m_queue.set(len(self.pending))
             self._m_occupancy.set(self.active)
             ran = self.active > 0
             t0 = time.perf_counter()
             if ran:
                 self._round()
-            h = tr.begin("evict", track="scheduler")
             drained.extend(self._evict())
-            tr.end(h)
             if ran:
                 # round-time estimators, fed only by ticks that launched
                 # a kernel (idle ticks are microseconds and would poison

@@ -423,3 +423,146 @@ def test_disabled_tracing_leaves_engine_results_bitwise_identical():
     for a, b in zip(reqs_off, reqs_on):
         assert jnp.array_equal(a.logits, b.logits)
         assert a.adc_steps == b.adc_steps
+
+
+# --- host phases: spans, pulls and queue wait --------------------------------
+
+PATHS = {"continuous": {}, "drain": {"continuous": False}}
+
+# child span -> the parent span it must sit inside, on the same track
+PARENTS = {
+    "continuous": {"validate": "enqueue", "density": "enqueue",
+                   "stage": "round", "launch": "round",
+                   "wait": "evict", "readout": "evict"},
+    "drain": {"validate": "enqueue", "density": "enqueue",
+              "stage": "legacy_batch", "launch": "legacy_batch",
+              "wait": "legacy_batch", "readout": "legacy_batch"},
+}
+
+
+def _serve_traced(n=5, tracer=None, **kw):
+    cfg, params, eng = _tiny_engine(tracer=tracer, **kw)
+    reqs = [eng.submit(_req(i)) for i in range(n)]
+    eng.run()
+    return eng, reqs
+
+
+def _inside(child, parent):
+    return (child[1] == parent[1] and parent[2] <= child[2]
+            and child[2] + child[3] <= parent[2] + parent[3])
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_host_phase_spans_nest_in_their_parents(path):
+    tracer = obs_trace.Tracer()
+    eng, reqs = _serve_traced(tracer=tracer, **PATHS[path])
+    spans = tracer.spans()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s[0], []).append(s)
+    for child, parent in PARENTS[path].items():
+        assert by_name.get(child), f"no {child!r} span"
+        for s in by_name[child]:
+            assert any(_inside(s, p) for p in by_name[parent]), \
+                f"{child} at {s[2]} outside every {parent}"
+    assert [s[4]["uid"] for s in by_name["enqueue"]] == \
+        [r.uid for r in reqs]
+    for s in by_name["stage"]:
+        assert s[4]["bytes"] > 0
+    # every request's readout sits in exactly one readout phase
+    parent = "evict" if path == "continuous" else "legacy_batch"
+    assert sum(s[4]["requests"] for s in by_name[parent]) == len(reqs)
+
+
+@pytest.mark.parametrize("path,noise", [("continuous", False),
+                                        ("continuous", True),
+                                        ("drain", False)])
+def test_pull_args_sum_to_host_pulls_total(path, noise):
+    from repro.core import ima as ima_lib
+    tracer = obs_trace.Tracer()
+    kw = dict(PATHS[path])
+    if noise:
+        kw["noise"] = ima_lib.IMANoiseModel()
+    eng, reqs = _serve_traced(tracer=tracer, **kw)
+    phases = ("admit", "evict", "legacy_batch")
+    pulls = sum(s[4]["pulls"] for s in tracer.spans() if s[0] in phases)
+    total = eng.metrics.value("host_pulls_total")
+    assert pulls == total > 0
+    if path == "continuous":
+        # argmax, adc, sops and skip per request; a noisy admission also
+        # pulls the request's seed word
+        assert total == 4 * len(reqs) + (len(reqs) if noise else 0)
+        admits = [s for s in tracer.spans() if s[0] == "admit"]
+        assert sum(s[4]["admitted"] for s in admits) == len(reqs)
+    else:
+        per_req = 3 + (reqs[0].skipped_block_ratio is not None)
+        assert total == per_req * len(reqs)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_counters_and_results_same_with_tracing_off_and_on(path):
+    import jax.numpy as jnp
+    eng_off, off = _serve_traced(**PATHS[path])
+    eng_on, on = _serve_traced(tracer=obs_trace.Tracer(), **PATHS[path])
+
+    def counters(eng):
+        return {(m["name"], tuple(sorted(m["labels"].items()))): m["value"]
+                for m in eng.metrics.to_dict()["metrics"]
+                if m["type"] == "counter"}
+
+    assert counters(eng_off) == counters(eng_on)
+    assert eng_off.metrics.value("host_pulls_total") > 0
+    for a, b in zip(off, on):
+        assert jnp.array_equal(a.logits, b.logits)
+        assert (a.pred, a.adc_steps, a.sops, a.skipped_block_ratio) == \
+            (b.pred, b.adc_steps, b.sops, b.skipped_block_ratio)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_queue_ms_is_stamped_once_and_within_latency(path):
+    tracer = obs_trace.Tracer()
+    eng, reqs = _serve_traced(tracer=tracer, **PATHS[path])
+    for r in reqs:
+        assert 0.0 <= r.queue_ms <= r.latency_ms
+    hist = eng.metrics.histogram("queue_wait_ms")
+    assert hist.total == len(reqs)
+    assert hist.sum == pytest.approx(sum(r.queue_ms for r in reqs))
+    if path == "continuous":
+        res = {s[4]["uid"]: s[4]["queue_ms"] for s in tracer.spans()
+               if s[1] and s[1].startswith("slot")}
+        assert res == {r.uid: r.queue_ms for r in reqs}
+
+
+def test_drain_latency_is_taken_after_the_device_wait():
+    tracer = obs_trace.Tracer()
+    eng, reqs = _serve_traced(n=2, tracer=tracer, continuous=False)
+    (wait,) = [s for s in tracer.spans() if s[0] == "wait"]
+    (readout,) = [s for s in tracer.spans() if s[0] == "readout"]
+    wait_end = wait[2] + wait[3]
+    for r in reqs:
+        # one clock (perf_counter); 1 ns covers the float round trip
+        t_done_ns = (r._t_submit * 1e3 + r.latency_ms) * 1e6
+        assert wait_end - 1 <= t_done_ns <= readout[2] + 1
+
+
+def test_queue_wait_counts_first_admission_only():
+    """A preempted request re-enters the queue; its queue wait stays the
+    one from submit to its first admission."""
+    cfg, params, eng = _tiny_engine()
+    reqs = [eng.submit(_req(i)) for i in range(2)]
+    eng.run(max_rounds=1)
+    first = {r.uid: r.queue_ms for r in reqs}
+    eng.preempt_request(reqs[0].uid, backoff=False)
+    eng.run()
+    assert {r.uid: r.queue_ms for r in reqs} == first
+    assert eng.metrics.histogram("queue_wait_ms").total == 2
+
+
+def test_engine_registers_no_energy_histogram():
+    """Energy is ``energy_report()``'s; the registry holds no per-request
+    energy series."""
+    eng, _ = _serve_traced(n=2)
+    names = {m["name"] for m in eng.metrics.to_dict()["metrics"]}
+    assert not any("pj" in n for n in names)
+    assert {"host_pulls_total", "queue_wait_ms",
+            "request_latency_ms"} <= names
