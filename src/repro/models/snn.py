@@ -621,6 +621,31 @@ def silicon_stream_admit(state: SiliconStreamState, mask, lengths,
         seed=jnp.asarray(seeds, jnp.int32))
 
 
+@jax.jit
+def silicon_stream_readout(state: SiliconStreamState, w_out, mask):
+    """Every finished slot's answer in one device program.
+
+    ``mask`` (S,) bool marks the slots whose streams ended.  All shapes
+    are fixed at the slot count, so the readout compiles once however
+    many slots finish together.  Returns per slot the logits
+    ``(counts / length) @ w_out``, their argmax, and the raw ``adc``,
+    ``sops`` and ``skip_acc`` accumulators (the caller normalizes those
+    by the length).  The product runs slot by slot at batch 1
+    (``lax.map``), the shape of a one-shot ``forward_silicon`` readout,
+    so the logits match it bit for bit: one (S, N) @ (N, C) product may
+    round a row differently.  Unmasked rows divide by 1 and mean nothing.
+    """
+    length = jnp.where(mask, state.length, 1).astype(jnp.float32)
+
+    def one(xs):
+        counts, n = xs
+        return ((counts[None] / n) @ w_out)[0]
+
+    logits = jax.lax.map(one, (state.counts, length))
+    return (logits, jnp.argmax(logits, axis=-1), state.adc, state.sops,
+            state.skip_acc)
+
+
 class SlotCheckpoint(NamedTuple):
     """Host-side snapshot of one serving slot's mid-flight stream state.
 

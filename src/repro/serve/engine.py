@@ -317,18 +317,18 @@ class SNNEventEngine:
             req.queue_ms = (now - req._t_submit) * 1e3
             self._m_queue_wait.observe(req.queue_ms)
 
-    def _to_host(self, x) -> np.ndarray:
-        """Pull one device value to the host.
+    def _to_host(self, x):
+        """Pull a device array, or a pytree of them, to the host in one read.
 
         Every device->host read of a result or a seed goes through here:
-        it counts in ``host_pulls_total`` whether tracing is on or off,
-        and it is the one read allowed under
+        it counts once in ``host_pulls_total`` whether tracing is on or
+        off, and it is the one read allowed under
         ``jax.transfer_guard_device_to_host("disallow")``, so serving
         under that guard proves the counter misses no pull.
         """
         self._m_pulls.inc()
         with jax.transfer_guard_device_to_host("allow"):
-            return np.asarray(x)
+            return jax.device_get(x)
 
     def submit(self, req: EventRequest) -> EventRequest:
         """Enqueue a request; returns it with ``state`` set.
@@ -418,15 +418,17 @@ class SNNEventEngine:
         t_done = time.perf_counter()
         with tr.span("readout", track="scheduler"):
             preds = jnp.argmax(logits, axis=-1)
-            skipped = tele.get("skipped_block_ratio")
+            rows = list(logits)             # one unstacking dispatch
+            preds, adc, sops, skipped = self._to_host(
+                (preds, tele["adc_steps"], tele["sops"],
+                 tele.get("skipped_block_ratio")))
             for i, req in enumerate(reqs):
-                req.logits = logits[i]
-                req.pred = int(self._to_host(preds[i]))
-                req.adc_steps = float(self._to_host(tele["adc_steps"][i]))
-                req.sops = float(self._to_host(tele["sops"][i]))
+                req.logits = rows[i]
+                req.pred = int(preds[i])
+                req.adc_steps = float(adc[i])
+                req.sops = float(sops[i])
                 if skipped is not None:
-                    req.skipped_block_ratio = float(
-                        self._to_host(skipped[i]))
+                    req.skipped_block_ratio = float(skipped[i])
                 if req._t_submit is not None:
                     req.latency_ms = (t_done - req._t_submit) * 1e3
                 req.state = lifecycle.COMPLETED
@@ -782,28 +784,33 @@ class SNNEventEngine:
             with tr.span("wait", track="scheduler"):
                 jax.block_until_ready(self._state)
             with tr.span("readout", track="scheduler"):
-                out = [self._complete_slot(i) for i in finished]
+                mask = np.zeros(self.b, bool)
+                mask[finished] = True
+                logits, *scalars = snn_lib.silicon_stream_readout(
+                    self._state, self.params["w_out"], mask)
+                rows = list(logits)         # one unstacking dispatch
+                pred, adc, sops, skip = self._to_host(scalars)
+                out = [self._complete_slot(i, rows[i], pred[i], adc[i],
+                                           sops[i], skip[i])
+                       for i in finished]
         if span is not None:
             tr.end(span, args={"requests": len(out),
                                "pulls": self._m_pulls.value - pulls0})
         return out
 
-    def _complete_slot(self, i: int) -> EventRequest:
-        """Read slot ``i``'s answer back and retire its request."""
+    def _complete_slot(self, i: int, logits: jax.Array, pred, adc, sops,
+                       skip) -> EventRequest:
+        """Retire slot ``i``'s request with its answer, already read back:
+        the device logits row and the host argmax and raw accumulators."""
         req = self._slot_req[i]
-        length = float(self._slot_len[i])
-        # batch-1 shaped readout: bitwise-matches the one-shot path
-        logits = (self._state.counts[i][None] / length) @ self.params["w_out"]
-        req.logits = logits[0]
-        req.pred = int(self._to_host(jnp.argmax(logits, axis=-1)[0]))
+        req.logits = logits
+        req.pred = int(pred)
         # f32 division: matches the one-shot telemetry normalization bit
         # for bit (tele / t_steps runs in f32 inside the jitted forward)
-        lf = np.float32(length)
-        req.adc_steps = float(
-            np.float32(self._to_host(self._state.adc[i])) / lf)
-        req.sops = float(np.float32(self._to_host(self._state.sops[i])) / lf)
-        req.skipped_block_ratio = float(
-            np.float32(self._to_host(self._state.skip_acc[i])) / lf)
+        lf = np.float32(self._slot_len[i])
+        req.adc_steps = float(np.float32(adc) / lf)
+        req.sops = float(np.float32(sops) / lf)
+        req.skipped_block_ratio = float(np.float32(skip) / lf)
         if req._t_submit is not None:
             req.latency_ms = (time.perf_counter() - req._t_submit) * 1e3
         req.state = lifecycle.COMPLETED

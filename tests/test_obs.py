@@ -489,14 +489,21 @@ def test_pull_args_sum_to_host_pulls_total(path, noise):
     total = eng.metrics.value("host_pulls_total")
     assert pulls == total > 0
     if path == "continuous":
-        # argmax, adc, sops and skip per request; a noisy admission also
-        # pulls the request's seed word
-        assert total == 4 * len(reqs) + (len(reqs) if noise else 0)
+        # one pull per evict that retires a request (2 slots, 5 requests
+        # of 2 rounds each: 3 such evicts); a noisy admission also pulls
+        # the request's seed word
+        evicts = [s for s in tracer.spans() if s[0] == "evict"]
+        assert [s[4]["pulls"] for s in evicts] == \
+            [int(s[4]["requests"] > 0) for s in evicts]
+        assert sum(s[4]["pulls"] for s in evicts) == 3
+        assert total == 3 + (len(reqs) if noise else 0)
         admits = [s for s in tracer.spans() if s[0] == "admit"]
         assert sum(s[4]["admitted"] for s in admits) == len(reqs)
     else:
-        per_req = 3 + (reqs[0].skipped_block_ratio is not None)
-        assert total == per_req * len(reqs)
+        # one pull per batch of 2: 3 batches
+        batches = [s for s in tracer.spans() if s[0] == "legacy_batch"]
+        assert [s[4]["pulls"] for s in batches] == [1, 1, 1]
+        assert total == 3
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))

@@ -12,6 +12,8 @@ on mixed event-stream lengths, and ``BatchedEngine``'s unsplit prefill key /
 admission-charged round budget.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,6 +57,25 @@ def _setup(**kw):
     cfg = _cfg(**kw)
     p = snn_lib.init_params(cfg, jax.random.PRNGKey(0))
     return cfg, p
+
+
+@functools.lru_cache(maxsize=2)
+def _readout_state(noisy: bool):
+    """64 slots after one 8-step round of streams 1..8 steps long, at
+    128 hidden columns and 10 classes: a readout's full-size operands."""
+    cfg = _cfg(n_hidden=128, n_classes=10)
+    p = snn_lib.init_params(cfg, jax.random.PRNGKey(0))
+    slots, r = 64, 8
+    lengths = np.asarray([1 + (5 * i) % r for i in range(slots)], np.int32)
+    seeds = np.arange(slots, dtype=np.int32) * 7919 + 1
+    st = snn_lib.silicon_stream_admit(
+        snn_lib.silicon_stream_init(cfg, slots), np.ones(slots, bool),
+        lengths, seeds)
+    ev = jax.random.bernoulli(jax.random.PRNGKey(1), 0.3,
+                              (r, slots, cfg.n_in)).astype(jnp.float32)
+    st = snn_lib.forward_silicon_stream(
+        p, ev, cfg, st, noise=ima_lib.IMANoiseModel() if noisy else None)
+    return cfg, p, st
 
 
 def _one_shot(p, cfg, req, noise=None):
@@ -353,6 +374,54 @@ class TestStreamStateUnit:
         assert float(st2.adc[0]) == 0.0 and float(st2.adc[2]) == 7.0
         assert int(st2.steps_done[0]) == 0 and int(st2.steps_done[1]) == 4
         assert list(np.asarray(st2.length)) == [6, 9, 9]
+
+    @pytest.mark.fast
+    @pytest.mark.parametrize("noisy", [False, True], ids=["clean", "noisy"])
+    @pytest.mark.parametrize("finished", ["one", "scattered", "all"])
+    def test_readout_matches_per_slot_eager_readout(self, noisy, finished):
+        """The jitted readout == the per-slot batch-1 eager readout it
+        replaced, bitwise: logits, argmax and the raw accumulators.  At
+        64 x 128 counts and 10 classes one (S, N) @ (N, C) product rounds
+        most rows differently on the CPU, so this pins the batch-1 form."""
+        cfg, p, st = _readout_state(noisy)
+        slots = {"one": [5], "scattered": [0, 3, 17, 40, 63],
+                 "all": list(range(st.counts.shape[0]))}[finished]
+        mask = np.zeros(st.counts.shape[0], bool)
+        mask[slots] = True
+        logits, pred, adc, sops, skip = snn_lib.silicon_stream_readout(
+            st, p["w_out"], mask)
+        for i in slots:
+            ref = (st.counts[i][None] / float(st.length[i])) @ p["w_out"]
+            np.testing.assert_array_equal(np.asarray(logits[i]),
+                                          np.asarray(ref[0]),
+                                          err_msg=f"slot {i}")
+            assert int(pred[i]) == int(jnp.argmax(ref, axis=-1)[0])
+            for got, acc in ((adc, st.adc), (sops, st.sops),
+                             (skip, st.skip_acc)):
+                assert np.float32(got[i]) == np.float32(acc[i])
+
+    @pytest.mark.fast
+    @pytest.mark.parametrize("noisy", [False, True], ids=["clean", "noisy"])
+    def test_readout_compiles_once_across_eviction_sizes(self, noisy):
+        """Ticks that retire 1, 2 or 3 requests share one readout entry:
+        its shapes are fixed at the slot count, never the finished count."""
+        from repro.obs import trace as obs_trace
+        cfg, p = _setup()
+        noise = ima_lib.IMANoiseModel() if noisy else None
+        tracer = obs_trace.Tracer()
+        engine = SNNEventEngine(cfg, p, batch_slots=4, seed=5, noise=noise,
+                                round_steps=4, pack_by_density=False,
+                                tracer=tracer)
+        key = jax.random.PRNGKey(14)
+        for i, t in enumerate([4, 8, 8, 12, 4, 4, 4, 8, 4]):
+            engine.submit(EventRequest(
+                uid=i, events=_events(jax.random.fold_in(key, i), t)))
+        snn_lib.silicon_stream_readout.clear_cache()
+        assert len(engine.run()) == 9
+        retired = {s[4]["requests"] for s in tracer.spans()
+                   if s[0] == "evict" and s[4]["requests"]}
+        assert len(retired) >= 2, retired
+        assert snn_lib.silicon_stream_readout._cache_size() == 1
 
     @pytest.mark.fast
     def test_stream_rejects_stacks(self):
