@@ -6,7 +6,7 @@ the ``kernels/ref.py`` oracles, run by XLA on the same chip.
 The model is the N-MNIST stand-in (``data/events.py``: 512 inputs, 20 time
 steps, 10 classes) on a KWN hidden layer of 128 columns with k = 3, the
 configuration ``examples/train_snn_events.py`` trains.  Weights and events
-come from fixed seeds.  Four phases, in order:
+come from fixed seeds.  Five phases, in order:
 
   a. silicon training: ``snn.train(silicon=True, noise=IMANoiseModel())``
      at batch 64, through the fused forward and the BPTT kernel;
@@ -14,7 +14,10 @@ come from fixed seeds.  Four phases, in order:
      and then noisy (the last round holds a partial batch);
   c. NLD ``forward_silicon(fused="seq")`` on a batch of 64 (2 x 128);
   d. the KWN stack ``hidden_layers=(256, 128)`` through ``forward_silicon``
-     and the engine's drain path.
+     and the engine's drain path;
+  e. NLD at the DVS128 width (32768 inputs, 2 x 128 ReLU branches, T = 30)
+     served by ``SNNEventEngine`` on its continuous slots: 96 requests,
+     each compared with a one-shot ``forward_silicon(fused="seq")``.
 
 Each phase prints its compile time (set-up, the first call), its steady
 time, the tile plan, and its parity against the oracle.  Clean outputs must
@@ -273,9 +276,10 @@ class Smoke:
                 seed = int(snn._noise_seed(r.key)) if noise is not None \
                     else 0
                 counts, adc = one(jnp.asarray(ev_np[r.uid]), seed)
-                # the engine's readout, op for op (batch-1, eager)
+                # the engine's readout, op for op (batch-1, eager), with
+                # the ramp-step mean divided on the device as it is there
                 logits = (counts[None] / t_len) @ p["w_out"]
-                adc_mean = float(np.float32(adc) / np.float32(t_len))
+                adc_mean = float(adc / t_len)
                 if not (jnp.array_equal(logits[0], r.logits)
                         and adc_mean == r.adc_steps):
                     bad += 1
@@ -458,6 +462,56 @@ class Smoke:
                    f"requests_differing={bad}/{REQUESTS}")
 
 
+    # -- phase e: NLD served continuously at the DVS128 width ----------------
+
+    def phase_nld_engine(self):
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from repro.data import events
+        from repro.serve.engine import EventRequest, SNNEventEngine
+
+        snn = self.snn
+        dcfg = dataclasses.replace(events.DVS_GESTURE, n_in=32768)
+        cfg = snn.SNNConfig(n_in=dcfg.n_in, n_steps=dcfg.n_steps,
+                            n_classes=dcfg.n_classes, mode="nld",
+                            n_branches=2, activation="relu")
+        p = snn.init_params(cfg, jax.random.PRNGKey(SEED + 5))
+        ev, _ = events.EventDataset(dcfg).sample(jax.random.PRNGKey(51),
+                                                 REQUESTS)
+        ev_np = np.asarray(ev)
+        eng = SNNEventEngine(cfg, p)
+        eng.submit(EventRequest(uid=-1, events=ev_np[0]))
+        t0 = time.perf_counter()
+        eng.run()
+        setup = time.perf_counter() - t0
+        reqs = [eng.submit(EventRequest(uid=i, events=ev_np[i]))
+                for i in range(REQUESTS)]
+        t0 = time.perf_counter()
+        done = eng.run()
+        steady = time.perf_counter() - t0
+        _say("e", n_in=cfg.n_in, slots=eng.b, continuous=eng.continuous,
+             requests=len(done), setup_compile_s=f"{setup:.3f}",
+             steady_s=f"{steady:.3f}",
+             conversions=eng.metrics.value("ima_conversions_total"))
+        bad = silent = 0
+        for r in reqs:
+            # eager, as a caller makes a one-shot request: the readout is
+            # then the batch-1 product the engine's readout reproduces
+            logits, tele = snn.forward_silicon(
+                p, jnp.asarray(ev_np[r.uid])[None], cfg, r.key, fused="seq")
+            bad += not (bool(jnp.array_equal(logits[0], r.logits))
+                        and float(tele["adc_steps"][0]) == r.adc_steps)
+            silent += not bool(jnp.any(r.logits != 0))
+        self.check("e", "engine_continuous", eng.continuous
+                   and len(done) == REQUESTS)
+        self.check("e", "engine_bitwise_vs_one_shot", bad == 0,
+                   f"requests_differing={bad}/{REQUESTS} "
+                   f"silent={silent}/{REQUESTS}")
+
+
 def main() -> int:
     here = os.path.dirname(os.path.abspath(__file__))
     sys.path.insert(0, os.path.join(here, "src"))
@@ -481,6 +535,7 @@ def main() -> int:
     smoke.phase_engine(p)
     smoke.phase_nld()
     smoke.phase_stack()
+    smoke.phase_nld_engine()
     if smoke.failures:
         print(f"chip_smoke: failed checks: {smoke.failures}",
               file=sys.stderr)

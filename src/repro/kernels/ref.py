@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro.core import ctrprng
 from repro.core import ima as ima_lib
 from repro.core import kwn as kwn_lib
+from repro.core import lif as lif_lib
 from repro.core import ternary as ternary_lib
 
 
@@ -475,3 +476,77 @@ def fused_macro_seq_vjp_ref(w, x, boundaries, levels, scale, v,
     xs = (t_ix, x) if noise is None else (t_ix, x, noise)
     v_fin, (spk_t, mask_t, steps_t, vtrace_t) = jax.lax.scan(step, v, xs)
     return v_fin, spk_t, mask_t, steps_t, vtrace_t
+
+
+# ---------------------------------------------------------------------------
+# Model-level reference: the NLD network (paper Eq. 2), end to end
+# ---------------------------------------------------------------------------
+
+def nld_forward_ref(params, events: jax.Array, cfg):
+    """Plain float32 forward of a one-layer NLD network: the reference that
+    ``snn.forward_silicon`` and ``SNNEventEngine`` are held to in NLD mode.
+
+    ``params`` holds ``"dend"`` (``dendrite.DendriteParams``) and
+    ``"w_out"``; ``events`` is (B, T, I) in {-1, 0, +1}; ``cfg`` is the
+    ``snn.SNNConfig`` (mode ``"nld"``).  No kernel, tile, slot or batch
+    layout is involved: every step is whole-array ``jax.numpy`` at
+    ``highest`` matmul precision.  Per time step t and soma p:
+
+        mac_j  = sum_i x_i(t) Wq_{i,j,p}                  branch MAC
+        a_j    = f_q(mac_j)                               NL-IMA ramp
+        V      = clip(beta V + g sum_j W^d_{j,p} a_j)     soma combine, LIF
+        spike where V >= v_th1, and V = v_reset there
+
+    and the logits are ``(spike counts / T) @ w_out``.
+
+    Where this departs from Eq. 2, it is the silicon's doing:
+
+    * synapses are quantized, not float: Wq = s (2 msb + lsb), the
+      twin-cell value of round(clip(W / s, -3, 3)), with one scale
+      s_{j,p} = max_i |W_{i,j,p}| / 3 per branch and soma;
+    * f_q is f sampled at 2**code_bits levels spread evenly over
+      ±``dend_range``: the ramp decides on the midpoints, so a branch MAC
+      takes the sample of its nearest level and saturates past the range;
+    * the drive is scaled by ``drive_gain`` (membrane units per unit of
+      drive), the membrane saturates at the 12-bit register's range, and it
+      resets after a spike; there is no stochastic near-threshold lift
+      (SNL is the KWN controller's, and NLD updates every soma);
+    * the ramp always runs all 2**code_bits - 1 steps (no early stop).
+
+    Returns (logits (B, C), spike counts (B, N), mean ramp steps per time
+    step (B,)).
+    """
+    with jax.default_matmul_precision("highest"):
+        w_syn = params["dend"].w_syn * params["dend"].mask     # (J, I, N)
+        n_branches, _, n = w_syn.shape
+        scale = jnp.maximum(jnp.max(jnp.abs(w_syn), axis=1) / 3.0, 1e-8)
+        w_int = jnp.round(jnp.clip(w_syn / scale[:, None, :], -3, 3))
+        w_cell = ternary_lib.weight_compose(
+            *ternary_lib.weight_decompose(w_int))
+        n_codes = 2 ** cfg.code_bits
+        grid = jnp.linspace(-cfg.dend_range, cfg.dend_range, n_codes)
+        bounds = 0.5 * (grid[1:] + grid[:-1])
+        levels = ima_lib.DENDRITE_ACTIVATIONS[cfg.activation](grid)
+        w_dend = params["dend"].w_dend
+        v_lim = lif_lib.vmem_limit(lif_lib.LIFParams().vmem_bits)
+        v_reset = lif_lib.LIFParams().v_reset
+
+        def step(v, x):
+            drive = jnp.zeros(v.shape, jnp.float32)
+            for j in range(n_branches):
+                mac = (x @ w_cell[j]) * scale[j]
+                act = levels[jnp.searchsorted(bounds, mac)]
+                drive = drive + act * w_dend[j]
+            v = jnp.clip(cfg.beta * v + drive * cfg.drive_gain,
+                         -v_lim, v_lim)
+            spike = (v >= cfg.v_th1).astype(jnp.float32)
+            return jnp.where(spike > 0, v_reset, v), spike
+
+        x = jnp.moveaxis(jnp.asarray(events, jnp.float32), 1, 0)
+        v0 = jnp.zeros((x.shape[1], n), jnp.float32)
+        _, spikes = jax.lax.scan(step, v0, x)
+        counts = jnp.sum(spikes, axis=0)
+        t = x.shape[0]
+        logits = (counts / t) @ params["w_out"]
+        steps = jnp.full((x.shape[1],), n_codes - 1, jnp.float32)
+        return logits, counts, steps

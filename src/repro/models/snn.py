@@ -628,12 +628,15 @@ def silicon_stream_readout(state: SiliconStreamState, w_out, mask):
     ``mask`` (S,) bool marks the slots whose streams ended.  All shapes
     are fixed at the slot count, so the readout compiles once however
     many slots finish together.  Returns per slot the logits
-    ``(counts / length) @ w_out``, their argmax, and the raw ``adc``,
-    ``sops`` and ``skip_acc`` accumulators (the caller normalizes those
-    by the length).  The product runs slot by slot at batch 1
-    (``lax.map``), the shape of a one-shot ``forward_silicon`` readout,
-    so the logits match it bit for bit: one (S, N) @ (N, C) product may
-    round a row differently.  Unmasked rows divide by 1 and mean nothing.
+    ``(counts / length) @ w_out``, their argmax, and the ``adc``, ``sops``
+    and ``skip_acc`` accumulators divided by the length.  The product runs
+    slot by slot at batch 1 (``lax.map``), the shape of a one-shot
+    ``forward_silicon`` readout, so the logits match it bit for bit: one
+    (S, N) @ (N, C) product may round a row differently.  The telemetry
+    is divided here, on the device, because the one-shot path divides its
+    telemetry by T on the device too, and a TPU's f32 division rounds
+    differently from the host's (30-step means read one ulp apart).
+    Unmasked rows divide by 1 and mean nothing.
     """
     length = jnp.where(mask, state.length, 1).astype(jnp.float32)
 
@@ -642,8 +645,8 @@ def silicon_stream_readout(state: SiliconStreamState, w_out, mask):
         return ((counts[None] / n) @ w_out)[0]
 
     logits = jax.lax.map(one, (state.counts, length))
-    return (logits, jnp.argmax(logits, axis=-1), state.adc, state.sops,
-            state.skip_acc)
+    return (logits, jnp.argmax(logits, axis=-1), state.adc / length,
+            state.sops / length, state.skip_acc / length)
 
 
 class SlotCheckpoint(NamedTuple):

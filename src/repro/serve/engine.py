@@ -275,6 +275,11 @@ class SNNEventEngine:
         self._m_skip = m.histogram("request_skipped_block_ratio",
                                    buckets=RATIO_BUCKETS)
         self._m_pulls = m.counter("host_pulls_total")
+        self._m_conversions = m.counter("ima_conversions_total")
+        # IMA-converted columns per slot-step: every branch of every soma
+        # in NLD mode, every column in KWN mode
+        self._columns = cfg.n_hidden * (cfg.n_branches if cfg.mode == "nld"
+                                        else 1)
         # continuous-path slot table (host shadows of the device state)
         self._state = (snn_lib.silicon_stream_init(cfg, batch_slots)
                        if continuous else None)
@@ -741,9 +746,15 @@ class SNNEventEngine:
         stream at a non-round-aligned offset (each distinct ``r`` compiles
         one jit entry, bounded by ``round_steps``).  Traced as a ``round``
         span holding its ``stage`` (host buffer and transfer) and
-        ``launch`` phases.
+        ``launch`` phases; its args give the kernel's converted
+        ``columns`` and the ``conversions`` the round asks of the IMA
+        (active slot-steps times columns), which ``ima_conversions_total``
+        sums whether tracing is on or off.
         """
         r = self.round_steps if r is None else r
+        busy = np.array([req is not None for req in self._slot_req])
+        conversions = self._columns * int(np.sum(np.minimum(
+            r, self._slot_len - self._slot_done)[busy]))
         tr = self.tracer
         span = tr.begin("round", track="scheduler")
         stage = tr.begin("stage", track="scheduler")
@@ -764,8 +775,11 @@ class SNNEventEngine:
                 noise=self.noise)
         self._slot_done = np.minimum(self._slot_done + r, self._slot_len)
         self._m_rounds.inc()
+        self._m_conversions.inc(conversions)
         if span is not None:
-            tr.end(span, args={"steps": r, "active": self.active})
+            tr.end(span, args={"steps": r, "active": self.active,
+                               "columns": self._columns,
+                               "conversions": conversions})
 
     def _evict(self) -> list[EventRequest]:
         """Retire every slot whose stream has ended: an ``evict`` span
@@ -801,16 +815,15 @@ class SNNEventEngine:
     def _complete_slot(self, i: int, logits: jax.Array, pred, adc, sops,
                        skip) -> EventRequest:
         """Retire slot ``i``'s request with its answer, already read back:
-        the device logits row and the host argmax and raw accumulators."""
+        the device logits row, and on the host the argmax and the per-step
+        means of the accumulators (divided on the device, as one-shot
+        telemetry is)."""
         req = self._slot_req[i]
         req.logits = logits
         req.pred = int(pred)
-        # f32 division: matches the one-shot telemetry normalization bit
-        # for bit (tele / t_steps runs in f32 inside the jitted forward)
-        lf = np.float32(self._slot_len[i])
-        req.adc_steps = float(np.float32(adc) / lf)
-        req.sops = float(np.float32(sops) / lf)
-        req.skipped_block_ratio = float(np.float32(skip) / lf)
+        req.adc_steps = float(adc)
+        req.sops = float(sops)
+        req.skipped_block_ratio = float(skip)
         if req._t_submit is not None:
             req.latency_ms = (time.perf_counter() - req._t_submit) * 1e3
         req.state = lifecycle.COMPLETED
@@ -920,46 +933,66 @@ class SNNEventEngine:
         return drained
 
     def energy_report(self, dataset: str) -> dict:
-        """Serving-side energy estimate from *measured* early-stop statistics.
+        """Serving-side energy estimate from the served traffic's measured
+        statistics, through the calibrated per-component model
+        (core.energy).
 
-        Uses the calibrated per-component model (core.energy) but replaces
-        the analytic early-stop saving with the mean ADC step count the KWN
-        controller actually reported for the served traffic.
+        KWN mode: ``kwn_step_energy`` at the dataset's calibrated input
+        spike rate, with the analytic early-stop saving replaced by the
+        mean ADC step count the KWN controller reported.  NLD mode:
+        ``nld_step_energy`` for the configured activation at the input
+        spike rate the traffic measured (its SOPs per step over the layer's
+        ``n_in * n_hidden`` synapses), converting every branch column on
+        the full ramp (NLD has no early stop); the dataset is checked but
+        its calibrated rate is not used.
 
-        Every statistic in the report — ADC steps, energy, and the
-        skipped-block ratio — is computed over the same population: the
-        completed requests that carry measured ``adc_steps``.  Returns
-        ``{}`` (documented contract, not an error) when there is nothing
-        to report: no completed KWN request with measured ADC statistics,
-        or the engine serves NLD mode, whose ramp always runs all
-        2**code_bits - 1 steps so there is no measured early-stop to
-        report.
+        Every statistic in the report — ADC steps, spike rate, energy, and
+        the skipped-block ratio — is computed over the same population: the
+        completed requests that carry measured ``adc_steps`` (and, in NLD
+        mode, measured ``sops``).  Returns ``{}`` (documented contract, not
+        an error) when that population is empty.
 
         Besides the population means, the report carries a
         ``per_request`` table (one row per completed request: uid,
         latency, measured ADC steps, per-request pJ/SOP from *that
-        request's* early-stop statistics, density) and — when latencies
-        were measured — the serving SLO summary ``latency_ms_mean`` /
+        request's* statistics, density) and — when latencies were
+        measured — the serving SLO summary ``latency_ms_mean`` /
         ``latency_ms_p50`` / ``latency_ms_p95``.
         """
-        done = [r for r in self.completed if r.adc_steps is not None]
-        if not done or self.cfg.mode != "kwn":
+        nld = self.cfg.mode == "nld"
+        done = [r for r in self.completed if r.adc_steps is not None
+                and (r.sops is not None or not nld)]
+        if not done:
             return {}
         if dataset not in energy_lib.SPIKE_RATES:
             raise ValueError(
                 f"unknown dataset {dataset!r} for the calibrated spike rate; "
                 f"expected one of {sorted(energy_lib.SPIKE_RATES)}")
+        synapses = float(self.cfg.n_in * self.cfg.n_hidden)
+
+        def rate_of(r):
+            return r.sops / synapses if nld else energy_lib.SPIKE_RATES[dataset]
+
+        def energy(rate, steps):
+            """(pJ per macro step, pJ per SOP) at this spike rate and ramp
+            depth."""
+            bd = (energy_lib.nld_step_energy(rate, self.cfg.activation) if nld
+                  else energy_lib.kwn_step_energy(self.cfg.k, rate,
+                                                  adc_steps=steps))
+            sops = energy_lib.sops_per_step(rate)
+            return bd.total, (bd.total / sops if sops > 0 else None)
+
         mean_steps = sum(r.adc_steps for r in done) / len(done)
+        mean_rate = sum(rate_of(r) for r in done) / len(done)
+        pj_step, pj_sop = energy(mean_rate, mean_steps)
         full = 2 ** self.cfg.code_bits - 1
-        spike_rate = energy_lib.SPIKE_RATES[dataset]
-        bd = energy_lib.kwn_step_energy(self.cfg.k, spike_rate,
-                                        adc_steps=mean_steps)
         rep = {
             "requests": len(done),
             "mean_adc_steps": mean_steps,
             "measured_adc_saving": 1.0 - mean_steps / full,
-            "pj_per_step": bd.total,
-            "pj_per_sop": bd.total / energy_lib.sops_per_step(spike_rate),
+            "spike_rate": mean_rate,
+            "pj_per_step": pj_step,
+            "pj_per_sop": pj_sop,
         }
         # same population as the ADC/energy stats above — a request that
         # carries a skip ratio but no adc_steps must not dilute the mean
@@ -968,7 +1001,6 @@ class SNNEventEngine:
         if skipped:
             # measured activity-plan saving, next to the early-stop saving
             rep["mean_skipped_block_ratio"] = sum(skipped) / len(skipped)
-        sops_ps = energy_lib.sops_per_step(spike_rate)
         rep["per_request"] = [
             {"uid": r.uid,
              "latency_ms": r.latency_ms,
@@ -977,9 +1009,7 @@ class SNNEventEngine:
              # "ran slowly" from "sat preempted" per request.
              "preempted_ms": r.preempted_ms,
              "adc_steps": r.adc_steps,
-             "pj_per_sop": energy_lib.kwn_step_energy(
-                 self.cfg.k, spike_rate,
-                 adc_steps=r.adc_steps).total / sops_ps,
+             "pj_per_sop": energy(rate_of(r), r.adc_steps)[1],
              "density": r.density}
             for r in done]
         lat = sorted(r.latency_ms for r in done if r.latency_ms is not None)

@@ -312,7 +312,9 @@ class TestEngineRegressions:
 
     @pytest.mark.fast
     def test_energy_report_empty_contract(self):
-        """{} for no measured requests, and for NLD mode (no early stop)."""
+        """{} for no measured requests, and in NLD mode for requests with
+        no measured SOPs (NLD energy is priced at the traffic's measured
+        spike rate)."""
         from repro.serve.engine import EventRequest
         assert self._engine().energy_report("nmnist") == {}
         nld = self._engine(mode="nld")

@@ -573,3 +573,40 @@ def test_engine_registers_no_energy_histogram():
     assert not any("pj" in n for n in names)
     assert {"host_pulls_total", "queue_wait_ms",
             "request_latency_ms"} <= names
+
+
+@pytest.mark.parametrize("mode", ["kwn", "nld"])
+def test_round_conversions_and_counter_same_with_tracing_off_and_on(mode):
+    """The ``round`` span's ``columns`` and ``conversions`` args, and the
+    ``ima_conversions_total`` counter, which counts with tracing off too:
+    every step of every request converts each column once (each branch of
+    each soma in NLD mode), however the streams split into rounds."""
+    import jax
+    from repro.models import snn as snn_lib
+    from repro.serve.engine import SNNEventEngine
+    cfg = snn_lib.SNNConfig(n_in=16, n_hidden=8, n_classes=3, n_steps=6,
+                            k=3, mode=mode, n_branches=2)
+    params = snn_lib.init_params(cfg, jax.random.PRNGKey(0))
+    lengths = [6, 4, 5, 2]
+    columns = 16 if mode == "nld" else 8
+
+    def serve(tracer):
+        eng = SNNEventEngine(cfg, params, batch_slots=2, round_steps=3,
+                             seed=1, tracer=tracer)
+        reqs = [eng.submit(_req(i, t=t)) for i, t in enumerate(lengths)]
+        eng.run()
+        return eng, reqs
+
+    tracer = obs_trace.Tracer()
+    eng_on, on = serve(tracer)
+    eng_off, off = serve(None)
+    rounds = [s for s in tracer.spans() if s[0] == "round"]
+    assert rounds and all(s[4]["columns"] == columns for s in rounds)
+    assert all(0 < s[4]["conversions"] <= columns * s[4]["steps"]
+               * s[4]["active"] for s in rounds)
+    want = columns * sum(lengths)
+    assert sum(s[4]["conversions"] for s in rounds) == want
+    assert eng_on.metrics.value("ima_conversions_total") == want
+    assert eng_off.metrics.value("ima_conversions_total") == want
+    for a, b in zip(off, on):
+        assert np.array_equal(np.asarray(a.logits), np.asarray(b.logits))

@@ -12,6 +12,7 @@ on mixed event-stream lengths, and ``BatchedEngine``'s unsplit prefill key /
 admission-charged round budget.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -279,6 +280,113 @@ class TestContinuousScheduling:
         assert rep["latency_ms_p50"] <= rep["latency_ms_p95"]
 
 
+def _nld_setup(n_in, n_hidden, activation, n_classes=5):
+    cfg = snn_lib.SNNConfig(n_in=n_in, n_hidden=n_hidden,
+                            n_classes=n_classes, n_steps=10, mode="nld",
+                            n_branches=2, activation=activation)
+    return cfg, snn_lib.init_params(cfg, jax.random.PRNGKey(21))
+
+
+def _ternary_events(key, t, n_in, rate=0.1):
+    on, neg = jax.random.split(key)
+    ev = jax.random.bernoulli(on, rate, (t, n_in)).astype(jnp.float32)
+    return np.asarray(
+        ev * jnp.where(jax.random.bernoulli(neg, 0.5, ev.shape), 1.0, -1.0))
+
+
+def _nld_serve(cfg, p, evs):
+    engine = SNNEventEngine(cfg, p, batch_slots=2, seed=5, round_steps=4)
+    assert engine.continuous
+    for i, e in enumerate(evs):
+        engine.submit(EventRequest(uid=i, events=e))
+    return engine.run()
+
+
+class TestNLDServing:
+    """NLD mode on the continuous engine: the served answers, one-shot
+    ``forward_silicon(fused="seq")`` and the plain reference
+    ``kernels.ref.nld_forward_ref`` agree.  Spike counts and ramp steps are
+    exact on all three; logits are bitwise between the two program paths.
+    Against the reference the logits are held to 1e-6: the readout
+    ``(counts / T) @ w_out`` is a float product whose summation order the
+    program (at its own batch shape) and the reference (at highest
+    precision) may each choose, so a logit may move by a few ulps while
+    every spike agrees.  Streams of 10 and 7 steps in rounds of 4 end
+    mid-round."""
+
+    @pytest.mark.parametrize("n_in,n_hidden", [(256, 32), (600, 64)],
+                             ids=["256x32", "600x64"])
+    @pytest.mark.parametrize("activation", ["relu", "quadratic"])
+    def test_engine_one_shot_and_reference_agree(self, n_in, n_hidden,
+                                                 activation):
+        from repro.kernels import ref
+        cfg, p = _nld_setup(n_in, n_hidden, activation)
+        key = jax.random.PRNGKey(22)
+        evs = [_ternary_events(jax.random.fold_in(key, i), t, n_in)
+               for i, t in enumerate([10, 7, 10])]
+        done = _nld_serve(cfg, p, evs)
+        # the same streams through a readout that is the identity: logits
+        # are then counts / T exactly, so the spike counts can be compared
+        cfg_id = dataclasses.replace(cfg, n_classes=n_hidden)
+        p_id = dict(p, w_out=jnp.eye(n_hidden, dtype=jnp.float32))
+        counts_served = _nld_serve(cfg_id, p_id, evs)
+        fwd_ref = jax.jit(ref.nld_forward_ref, static_argnums=2)
+        spiked = 0.0
+        for r, r_id in zip(done, counts_served):
+            ev = jnp.asarray(evs[r.uid])[None]
+            t = ev.shape[1]
+            logits, tele = snn_lib.forward_silicon(p, ev, cfg, r.key,
+                                                   fused="seq")
+            np.testing.assert_array_equal(np.asarray(r.logits),
+                                          np.asarray(logits[0]))
+            assert r.adc_steps == float(tele["adc_steps"][0]) == 31.0
+            ref_logits, ref_counts, ref_steps = fwd_ref(p, ev, cfg)
+            np.testing.assert_array_equal(
+                np.rint(np.asarray(r_id.logits) * t), ref_counts[0])
+            id_logits, _ = snn_lib.forward_silicon(p_id, ev, cfg_id, r.key,
+                                                   fused="seq")
+            np.testing.assert_array_equal(
+                np.rint(np.asarray(id_logits[0]) * t), ref_counts[0])
+            assert r.adc_steps == float(ref_steps[0])
+            np.testing.assert_allclose(np.asarray(r.logits),
+                                       np.asarray(ref_logits[0]),
+                                       rtol=0, atol=1e-6)
+            spiked += float(jnp.sum(ref_counts))
+        assert spiked > 0            # the comparison is not of silence
+
+    @pytest.mark.fast
+    def test_energy_report_nld_pins_calibrated_model(self):
+        """NLD energy comes from ``nld_step_energy`` at the traffic's
+        measured input spike rate: streams with exactly 12 events in each
+        step of 1250 inputs run at DVS Gesture's calibrated 0.96 %, so the
+        report reads the model's DVS Gesture pJ/SOP (Table I: 2.3)."""
+        from repro.core import energy as energy_lib
+        cfg, p = _nld_setup(1250, 32, "relu")
+        rate = energy_lib.SPIKE_RATES["dvs_gesture"]
+        rng = np.random.default_rng(0)
+        evs = []
+        for _ in range(3):
+            e = np.zeros((10, cfg.n_in), np.float32)
+            for row in e:
+                row[rng.choice(cfg.n_in, 12, replace=False)] = \
+                    rng.choice([-1.0, 1.0], 12)
+            evs.append(e)
+        engine = SNNEventEngine(cfg, p, batch_slots=2, round_steps=4)
+        for i, e in enumerate(evs):
+            engine.submit(EventRequest(uid=i, events=e))
+        engine.run()
+        rep = engine.energy_report("dvs_gesture")
+        want = energy_lib.nld_pj_per_sop(rate, "relu")
+        assert rep["requests"] == 3
+        assert rep["mean_adc_steps"] == 31.0
+        assert rep["measured_adc_saving"] == 0.0
+        assert rep["spike_rate"] == pytest.approx(rate, rel=1e-12)
+        assert rep["pj_per_sop"] == pytest.approx(want, rel=1e-12)
+        assert round(rep["pj_per_sop"], 1) == 2.3
+        for row in rep["per_request"]:
+            assert row["pj_per_sop"] == pytest.approx(want, rel=1e-12)
+
+
 class TestRowCtlKernel:
     """kernel-level row_ctl lane: per-row streams == batch-1 scalar runs."""
 
@@ -380,7 +488,8 @@ class TestStreamStateUnit:
     @pytest.mark.parametrize("finished", ["one", "scattered", "all"])
     def test_readout_matches_per_slot_eager_readout(self, noisy, finished):
         """The jitted readout == the per-slot batch-1 eager readout it
-        replaced, bitwise: logits, argmax and the raw accumulators.  At
+        replaced, bitwise: logits, argmax and the accumulators divided by
+        the slot's length, as one-shot telemetry is on the device.  At
         64 x 128 counts and 10 classes one (S, N) @ (N, C) product rounds
         most rows differently on the CPU, so this pins the batch-1 form."""
         cfg, p, st = _readout_state(noisy)
@@ -398,7 +507,8 @@ class TestStreamStateUnit:
             assert int(pred[i]) == int(jnp.argmax(ref, axis=-1)[0])
             for got, acc in ((adc, st.adc), (sops, st.sops),
                              (skip, st.skip_acc)):
-                assert np.float32(got[i]) == np.float32(acc[i])
+                assert np.float32(got[i]) == \
+                    np.float32(acc[i] / st.length[i].astype(jnp.float32))
 
     @pytest.mark.fast
     @pytest.mark.parametrize("noisy", [False, True], ids=["clean", "noisy"])

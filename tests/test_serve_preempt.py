@@ -178,6 +178,37 @@ class TestPreemptionParity:
         assert [r.uid for r in done] == [0, 1, 2, 3, 4]
         _assert_parity(engine, p, cfg, reqs, noise=noise)
 
+    @pytest.mark.parametrize("activation", ["relu", "quadratic"])
+    def test_nld_slot_preempted_and_resumed_bitwise(self, activation):
+        """An NLD slot checkpointed at a non-aligned step and restored
+        into whichever slot is free finishes bitwise equal to one-shot."""
+        cfg = snn_lib.SNNConfig(n_in=256, n_hidden=32, n_classes=3,
+                                n_steps=10, mode="nld", n_branches=2,
+                                activation=activation)
+        p = snn_lib.init_params(cfg, jax.random.PRNGKey(7))
+        key = jax.random.PRNGKey(8)
+        engine = SNNEventEngine(cfg, p, batch_slots=2, seed=6, round_steps=4)
+        reqs = []
+        for i, t in enumerate([13, 10, 7, 9]):
+            ev = np.asarray(jax.random.randint(jax.random.fold_in(key, i),
+                                               (t, cfg.n_in), -1, 2),
+                            np.float32)
+            ev *= np.asarray(jax.random.bernoulli(
+                jax.random.fold_in(key, 100 + i), 0.2, ev.shape))
+            reqs.append(engine.submit(EventRequest(uid=i, events=ev)))
+        fired = []
+
+        def hook(eng):
+            if not fired and 0 in [r.uid for r in eng._slot_req if r]:
+                victim = eng.preempt_request(0, at_step=6, backoff=False)
+                assert victim._ckpt.steps_done == 6
+                fired.append(True)
+
+        done = engine.run(round_hook=hook)
+        assert fired and engine.preemption_count == 1
+        assert [r.uid for r in done] == [0, 1, 2, 3]
+        _assert_parity(engine, p, cfg, reqs)
+
     @pytest.mark.parametrize("noise", [None, _NOISE],
                              ids=["clean", "noisy"])
     @pytest.mark.parametrize("case", range(4))

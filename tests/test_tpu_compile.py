@@ -121,6 +121,22 @@ def test_nld_2x128_forward(chip):
              _params(chip, cfg), events, key)
 
 
+def test_nld_engine_round_dvs_width(chip):
+    """One continuous-batching round of NLD at the DVS128 sensor width:
+    32768 inputs into 2 x 128 branch-major columns, 64 slots, 8 steps —
+    the NLD kernel at K = 32768, with the round's weight packing."""
+    cfg = snn.SNNConfig(n_in=32768, n_hidden=128, n_classes=11, n_steps=30,
+                        mode="nld", n_branches=2, activation="relu")
+    with jax.default_device(next(iter(chip.device_set))):
+        plan = fused_macro.plan_tiles(64, cfg.n_in, 256, 128, 8, mode="nld",
+                                      n_branches=2)
+        assert plan.vmem_bytes <= fused_macro.vmem_limit_bytes()
+    state = _on(chip, jax.eval_shape(lambda: snn.silicon_stream_init(cfg, 64)))
+    events = _on(chip, jax.ShapeDtypeStruct((8, 64, cfg.n_in), jnp.float32))
+    _compile(chip, lambda p, ev, st: snn.forward_silicon_stream(
+        p, ev, cfg, st), _params(chip, cfg), events, state)
+
+
 def test_stack_256_128_at_32_rows(chip):
     """The stacked KWN kernel, layers (256, 128), at 32 rows, noisy."""
     cfg = _cfg(hidden_layers=(256, 128))
