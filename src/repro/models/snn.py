@@ -622,6 +622,25 @@ def silicon_stream_admit(state: SiliconStreamState, mask, lengths,
 
 
 @jax.jit
+def silicon_stream_keys(base_key, orders, given, derive):
+    """Every fresh admission's key and seed word in one device program.
+
+    ``orders`` (S,) uint32 are the requests' submission indices, ``given``
+    (S, 2) uint32 the keys their callers set, and ``derive`` (S,) bool
+    marks the slots whose key is folded from ``base_key``.  All shapes are
+    fixed at the slot count, so the program compiles once however many
+    requests a pass admits.  Returns the (S, 2) keys,
+    ``fold_in(base_key, order)`` where ``derive`` is set and ``given``
+    elsewhere, and their (S,) counter-PRNG seed words (``_noise_seed``),
+    each bitwise equal to the per-request call.  Rows of slots that admit
+    nothing mean nothing.
+    """
+    derived = jax.vmap(lambda o: jax.random.fold_in(base_key, o))(orders)
+    keys = jnp.where(derive[:, None], derived, given)
+    return keys, jax.vmap(_noise_seed)(keys)
+
+
+@jax.jit
 def silicon_stream_readout(state: SiliconStreamState, w_out, mask):
     """Every finished slot's answer in one device program.
 

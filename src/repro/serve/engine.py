@@ -276,6 +276,7 @@ class SNNEventEngine:
                                    buckets=RATIO_BUCKETS)
         self._m_pulls = m.counter("host_pulls_total")
         self._m_conversions = m.counter("ima_conversions_total")
+        self._m_key_calls = m.counter("admission_key_calls_total")
         # IMA-converted columns per slot-step: every branch of every soma
         # in NLD mode, every column in KWN mode
         self._columns = cfg.n_hidden * (cfg.n_branches if cfg.mode == "nld"
@@ -480,8 +481,8 @@ class SNNEventEngine:
     # Continuous path: step-granularity rounds over persistent slots
     # ------------------------------------------------------------------
 
-    def _request_seed(self, req: EventRequest) -> int:
-        """Per-request counter-PRNG seed word, assigned at admission.
+    def _key_fresh(self, fresh: list[tuple[int, EventRequest]]) -> int:
+        """Key and seed one pass's fresh admissions in one device program.
 
         Each request gets its own key (folded from the engine seed by
         submission index unless the caller set ``req.key``), so its noise
@@ -489,12 +490,37 @@ class SNNEventEngine:
         request, independent of co-batched traffic or admission order.
         A one-shot ``forward_silicon(p, ev[None], cfg, req.key,
         fused="seq", noise=...)`` reproduces the served result bitwise.
+        One ``snn.silicon_stream_keys`` call per pass derives the keys and
+        seed words (counted in ``admission_key_calls_total``); the derived
+        keys are unstacked in one dispatch, and a noisy engine pulls the
+        seed words in one read (clean serving never reads them).  Returns
+        how many keys the pass derived.
         """
-        if req.key is None:
-            req.key = jax.random.fold_in(self._base_key, req._order)
-        if self.noise is None:
-            return 0              # clean serving never reads the seed word
-        return int(self._to_host(snn_lib._noise_seed(req.key)))
+        orders = np.zeros(self.b, np.uint32)
+        derive = np.zeros(self.b, bool)
+        caller = {}
+        for slot, req in fresh:
+            if req.key is None:
+                orders[slot], derive[slot] = req._order, True
+            else:
+                caller[slot] = jax.random.key_data(req.key)
+        given = np.zeros((self.b, 2), np.uint32)
+        if caller:
+            given = jnp.asarray(given).at[np.asarray(list(caller))].set(
+                jnp.stack(list(caller.values())))
+        keys, seeds = snn_lib.silicon_stream_keys(
+            self._base_key, orders, given, derive)
+        self._m_key_calls.inc()
+        if derive.any():
+            rows = list(keys)               # one unstacking dispatch
+            for slot, req in fresh:
+                if derive[slot]:
+                    req.key = rows[slot]
+        if self.noise is not None:
+            seeds = self._to_host(seeds)
+            for slot, _ in fresh:
+                self._slot_seed[slot] = seeds[slot]
+        return int(derive.sum())
 
     # --- deadline bookkeeping -----------------------------------------
 
@@ -563,18 +589,19 @@ class SNNEventEngine:
 
     # --- admission ----------------------------------------------------
 
-    def _admit(self) -> int:
-        """Fill free slots from the queue; returns how many were admitted."""
+    def _admit(self) -> tuple[int, int]:
+        """Fill free slots from the queue; returns how many were admitted
+        and how many of their keys were derived."""
         free = [i for i, r in enumerate(self._slot_req) if r is None]
         if not free or not self.pending:
-            return 0
+            return 0, 0
         # backoff gate: a freshly preempted request sits out its
         # exponential-backoff window (measured in scheduling ticks, which
         # advance even on idle rounds, so the window always expires)
         eligible = [r for r in self.pending
                     if r._not_before <= self._rounds_total]
         if not eligible:
-            return 0
+            return 0, 0
         scheduled = any(r.priority != 0 or r.deadline_ms is not None
                         or r._ckpt is not None for r in eligible)
         if scheduled:
@@ -599,6 +626,7 @@ class SNNEventEngine:
         taken = {id(r) for r in chosen}
         self.pending = [r for r in self.pending if id(r) not in taken]
         mask = np.zeros(self.b, bool)
+        fresh: list[tuple[int, EventRequest]] = []
         tr = self.tracer
         now = time.perf_counter()
         for slot, req in zip(free, chosen):
@@ -637,12 +665,15 @@ class SNNEventEngine:
             else:
                 self._slot_len[slot] = np.asarray(req.events).shape[0]
                 self._slot_done[slot] = 0
-                self._slot_seed[slot] = self._request_seed(req)
+                self._slot_seed[slot] = 0   # a noisy engine's comes next
                 mask[slot] = True
-        if mask.any():
+                fresh.append((slot, req))
+        derived = 0
+        if fresh:
+            derived = self._key_fresh(fresh)
             self._state = snn_lib.silicon_stream_admit(
                 self._state, mask, self._slot_len, self._slot_seed)
-        return len(chosen)
+        return len(chosen), derived
 
     # --- preemption ---------------------------------------------------
 
@@ -897,9 +928,9 @@ class SNNEventEngine:
             tr.end(h)
             h = tr.begin("admit", track="scheduler")
             pulls0 = self._m_pulls.value
-            admitted = self._admit()
+            admitted, keys = self._admit()
             if h is not None:
-                tr.end(h, args={"admitted": admitted,
+                tr.end(h, args={"admitted": admitted, "keys": keys,
                                 "pulls": self._m_pulls.value - pulls0})
             self._m_queue.set(len(self.pending))
             self._m_occupancy.set(self.active)

@@ -490,15 +490,26 @@ def test_pull_args_sum_to_host_pulls_total(path, noise):
     assert pulls == total > 0
     if path == "continuous":
         # one pull per evict that retires a request (2 slots, 5 requests
-        # of 2 rounds each: 3 such evicts); a noisy admission also pulls
-        # the request's seed word
+        # of 2 rounds each: 3 such evicts); a noisy engine also pulls the
+        # seed words of each pass that admits a fresh request, once
         evicts = [s for s in tracer.spans() if s[0] == "evict"]
         assert [s[4]["pulls"] for s in evicts] == \
             [int(s[4]["requests"] > 0) for s in evicts]
         assert sum(s[4]["pulls"] for s in evicts) == 3
-        assert total == 3 + (len(reqs) if noise else 0)
         admits = [s for s in tracer.spans() if s[0] == "admit"]
+        passes = [s for s in admits if s[4]["admitted"]]
+        assert all(s[4]["pulls"] == s[4]["keys"] == 0 for s in admits
+                   if not s[4]["admitted"])
+        assert [s[4]["pulls"] for s in passes] == [int(noise)] * len(passes)
+        assert total == 3 + (len(passes) if noise else 0)
         assert sum(s[4]["admitted"] for s in admits) == len(reqs)
+        # every request is admitted fresh with its key derived: one
+        # batched key call per admitting pass, tracing on or off
+        assert sum(s[4]["keys"] for s in admits) == len(reqs)
+        eng_off, _ = _serve_traced(**kw)
+        for e in (eng, eng_off):
+            assert e.metrics.value("admission_key_calls_total") == \
+                len(passes)
     else:
         # one pull per batch of 2: 3 batches
         batches = [s for s in tracer.spans() if s[0] == "legacy_batch"]

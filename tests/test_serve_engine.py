@@ -534,6 +534,64 @@ class TestStreamStateUnit:
         assert snn_lib.silicon_stream_readout._cache_size() == 1
 
     @pytest.mark.fast
+    @pytest.mark.parametrize("case", ["full", "partial", "mixed"])
+    def test_keys_match_per_request_fold_in(self, case):
+        """The batched admission keys == the per-request ``fold_in`` and
+        ``_noise_seed`` they replaced, bitwise: a full pass of 64, a
+        partial pass, and a pass that mixes caller-set and derived keys
+        (whose key is kept, and whose seed is its own key's)."""
+        slots = 64
+        base = jax.random.PRNGKey(2 ** 31 + 17)
+        orders = (np.arange(slots, dtype=np.uint32) * 7919 +
+                  np.uint32(2 ** 31 - 5))
+        derive = {"full": np.ones(slots, bool),
+                  "partial": np.isin(np.arange(slots), [3, 10, 40]),
+                  "mixed": np.arange(slots) % 2 == 0}[case]
+        caller = (~derive if case == "mixed"
+                  else np.zeros(slots, bool))
+        given = np.zeros((slots, 2), np.uint32)
+        for i in np.flatnonzero(caller):
+            given[i] = np.asarray(jax.random.PRNGKey(1000 + i))
+        keys, seeds = snn_lib.silicon_stream_keys(base, orders, given,
+                                                  derive)
+        for i in np.flatnonzero(derive | caller):
+            want = (jax.random.fold_in(base, int(orders[i])) if derive[i]
+                    else jnp.asarray(given[i]))
+            np.testing.assert_array_equal(np.asarray(keys[i]),
+                                          np.asarray(want),
+                                          err_msg=f"slot {i}")
+            assert int(seeds[i]) == int(snn_lib._noise_seed(want)), i
+
+    @pytest.mark.fast
+    @pytest.mark.parametrize("noisy", [False, True], ids=["clean", "noisy"])
+    def test_keys_compile_once_across_admission_sizes(self, noisy):
+        """Passes that admit 1 to 4 fresh requests share one key program:
+        its shapes are fixed at the slot count.  Every derived key is the
+        engine seed's ``fold_in`` of the submission index."""
+        from repro.obs import trace as obs_trace
+        cfg, p = _setup()
+        noise = ima_lib.IMANoiseModel() if noisy else None
+        tracer = obs_trace.Tracer()
+        engine = SNNEventEngine(cfg, p, batch_slots=4, seed=5, noise=noise,
+                                round_steps=4, pack_by_density=False,
+                                tracer=tracer)
+        key = jax.random.PRNGKey(15)
+        reqs = [engine.submit(EventRequest(
+            uid=i, events=_events(jax.random.fold_in(key, i), t)))
+            for i, t in enumerate([4, 8, 8, 12, 4, 4, 4, 8, 4])]
+        snn_lib.silicon_stream_keys.clear_cache()
+        assert len(engine.run()) == 9
+        sizes = {s[4]["keys"] for s in tracer.spans()
+                 if s[0] == "admit" and s[4]["keys"]}
+        assert len(sizes) >= 2, sizes
+        assert snn_lib.silicon_stream_keys._cache_size() == 1
+        for r in reqs:
+            np.testing.assert_array_equal(
+                np.asarray(r.key),
+                np.asarray(jax.random.fold_in(jax.random.PRNGKey(5),
+                                              r._order)))
+
+    @pytest.mark.fast
     def test_stream_rejects_stacks(self):
         cfg = snn_lib.SNNConfig(n_in=16, n_hidden=8, n_classes=2,
                                 hidden_layers=(8, 8), k_layers=(2, 2))

@@ -178,6 +178,46 @@ class TestPreemptionParity:
         assert [r.uid for r in done] == [0, 1, 2, 3, 4]
         _assert_parity(engine, p, cfg, reqs, noise=noise)
 
+    @pytest.mark.fast
+    @pytest.mark.parametrize("noise", [None, _NOISE],
+                             ids=["clean", "noisy"])
+    def test_readmission_shares_a_pass_with_fresh_admits(self, noise):
+        """One admission pass restores a checkpoint and admits two fresh
+        requests, one with a caller-set key: the checkpoint keeps its
+        stored seed, the caller's key is kept, and every request still
+        matches one-shot ``forward_silicon`` bitwise."""
+        from repro.obs import trace as obs_trace
+        cfg, p = _setup()
+        tracer = obs_trace.Tracer()
+        engine = SNNEventEngine(cfg, p, batch_slots=3, seed=12,
+                                round_steps=4, noise=noise,
+                                pack_by_density=False, tracer=tracer)
+        key = jax.random.PRNGKey(21)
+        own = jax.random.PRNGKey(77)
+        reqs = [engine.submit(EventRequest(
+            uid=i, events=_events(jax.random.fold_in(key, i), t),
+            key=own if i == 4 else None))
+            for i, t in enumerate([12, 4, 4, 8, 6])]
+        fired = []
+
+        def hook(eng):
+            # after the first round requests 1 and 2 have left; request 0
+            # leaves too, so the next pass fills all three slots
+            if not fired:
+                eng.preempt_request(0, backoff=False)
+                fired.append(True)
+
+        done = engine.run(round_hook=hook)
+        assert [r.uid for r in done] == [0, 1, 2, 3, 4]
+        assert reqs[4].key is own
+        passes = [s[4] for s in tracer.spans()
+                  if s[0] == "admit" and s[4]["admitted"]]
+        assert [(a["admitted"], a["keys"]) for a in passes] == \
+            [(3, 3), (3, 1)]
+        if noise is not None:
+            assert [a["pulls"] for a in passes] == [1, 1]
+        _assert_parity(engine, p, cfg, reqs, noise=noise)
+
     @pytest.mark.parametrize("activation", ["relu", "quadratic"])
     def test_nld_slot_preempted_and_resumed_bitwise(self, activation):
         """An NLD slot checkpointed at a non-aligned step and restored
