@@ -3,19 +3,14 @@
 # is the full suite (~10 min; the slow tier spawns multi-device
 # subprocesses and training loops).  `make lint` runs ruff when installed
 # plus the stdlib fallback linter (tools/lint.py) always, so the gate works
-# in the minimal container too.  `make bench` runs the fused-macro
-# benchmark — including the activity-gating density sweep — writes the
-# machine-readable perf-trajectory records CI uploads per PR, and
-# validates their schema.  `make bench-check` additionally gates clean-path
-# regressions against the committed BENCH_fused_macro.json (>20 %
-# normalized median fails; see tools/check_bench.py).
+# in the minimal container too.
 
 PYTHONPATH_SRC := PYTHONPATH=src$(if $(PYTHONPATH),:$(PYTHONPATH))
 PYTEST := $(PYTHONPATH_SRC) python -m pytest
 LINT_PATHS := src tests benchmarks examples tools
 
 .PHONY: smoke train-smoke serve-smoke chaos-smoke obs-smoke test lint \
-	bench bench-check tune tune-smoke
+	tune tune-smoke
 
 # `smoke`, `train-smoke`, and `serve-smoke` partition the fast tier
 # (silicon-training tests are owned by `train-smoke`, serving-engine and
@@ -66,17 +61,6 @@ lint:
 		echo "ruff not installed; stdlib fallback only (CI runs ruff)"; \
 	fi
 	python tools/lint.py $(LINT_PATHS)
-
-bench:
-	$(PYTHONPATH_SRC) python benchmarks/bench_fused_macro.py \
-		--out BENCH_fused_macro.json
-	python tools/check_bench.py BENCH_fused_macro.json
-
-bench-check:
-	@cp BENCH_fused_macro.json /tmp/bench_baseline.json
-	$(MAKE) bench
-	python tools/check_bench.py BENCH_fused_macro.json \
-		--baseline /tmp/bench_baseline.json
 
 # Regenerate the persistent tile-plan cache (PLAN_CACHE_fused_macro.json):
 # autotune the canonical launch shapes on this machine and persist the

@@ -17,16 +17,15 @@ import time
 from repro import compile_cache
 
 from benchmarks import (ablation_k_sweep, ablation_kwn_lm,
-                        bench_fused_macro, fig3d_weight_impl, fig5b_snl,
-                        fig6c_nlq, fig7_ima, fig8_accuracy, fig9_energy,
-                        latency_kwn, roofline_report, table1_comparison)
+                        fig3d_weight_impl, fig5b_snl, fig6c_nlq, fig7_ima,
+                        fig8_accuracy, fig9_energy, latency_kwn,
+                        roofline_report, table1_comparison)
 
 BENCHES = {
     "fig3d_weight_impl": fig3d_weight_impl,
     "fig7_ima": fig7_ima,
     "fig9_energy": fig9_energy,
     "latency_kwn": latency_kwn,
-    "bench_fused_macro": bench_fused_macro,
     "fig5b_snl": fig5b_snl,
     "fig6c_nlq": fig6c_nlq,
     "fig8_accuracy": fig8_accuracy,

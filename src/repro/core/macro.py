@@ -17,8 +17,8 @@ Two execution paths:
     pipeline stage is a separate jnp/kernel call with HBM-visible
     intermediates — use it when you need those intermediates (codebook
     studies, training STE paths);
-  * **fused** (``pack_kwn_weights``/``pack_nld_weights`` + ``fused_step`` /
-    ``fused_seq``): the whole MAC -> IMA -> mode-head -> LIF step runs
+  * **fused** (``pack_kwn_weights``/``pack_nld_weights`` + ``fused_seq``):
+    the whole MAC -> IMA -> mode-head -> LIF step runs
     inside one Pallas kernel (``repro.kernels.fused_macro``), the way the
     silicon never leaves the macro.  Layers wider than one 256x128 macro
     are tiled onto the virtual macro grid *inside* the kernel (column tiles
@@ -322,44 +322,13 @@ def fused_kernel_noise(fw: FusedMacroWeights,
     weights actually sweep (integer MAC units in KWN mode, float units in
     NLD mode — both are ``±cfg.mac_range`` by construction of the packers).
     Returns None when the config is ideal, so callers can pass the result
-    straight to ``fused_step``/``fused_seq``.
+    straight to ``fused_seq``.
     """
     if cfg.ima_noise is None:
         return None
     cb = ima_lib.RampCodebook(fw.levels, fw.boundaries,
                               -cfg.mac_range, cfg.mac_range)
     return ima_lib.kernel_noise_params(cfg.ima_noise, cb)
-
-
-def fused_step(spikes: jax.Array, fw: FusedMacroWeights, v: jax.Array,
-               noise: jax.Array | None = None, *, k: int = 12,
-               drive_gain: float = 1.0, beta: float = 0.9,
-               v_th1: float = 1.0, v_th2: float = 0.6,
-               v_reset: float = 0.0, v_lim: float = 8.0,
-               use_snl: bool = True, ima_noise=None, snl_amp: float = 0.0,
-               gate: bool = True, mac_telemetry: bool = True,
-               seed=0, step_offset=0):
-    """One fused macro time step: spikes (..., I), v/noise (..., N).
-
-    ``ima_noise`` (``ima.IMAKernelNoise``, see ``fused_kernel_noise``)
-    enables the in-kernel Fig. 7 conversion-error model; with
-    ``noise=None`` the SNL stream is generated in-kernel too (counter PRNG
-    at ``snl_amp``), keyed on ``(seed, step_offset)``.  ``gate`` /
-    ``mac_telemetry`` select activity-gated execution (default, output-
-    invariant) and the raw-MAC HBM stack (mac is None when off).
-    Returns (v_out, spikes_out, mask, adc_steps, mac) — the LIF state update,
-    the KWN winner mask (ones in NLD mode), the per-row early-stop ADC step
-    count, and the raw integer-unit MAC for telemetry.
-    """
-    from repro.kernels import ops as kernel_ops
-    s = ternary_lib.ternary_input_encode(spikes)
-    mac, v_out, spk, mask, steps = kernel_ops.fused_macro_step(
-        s, fw.msb, fw.lsb, fw.boundaries, fw.levels, fw.scale, v, noise,
-        fw.w_dend, mode=fw.mode, k=k, drive_gain=drive_gain, beta=beta,
-        v_th1=v_th1, v_th2=v_th2, v_reset=v_reset, v_lim=v_lim,
-        use_snl=use_snl, ima_noise=ima_noise, snl_amp=snl_amp, gate=gate,
-        mac_telemetry=mac_telemetry, seed=seed, step_offset=step_offset)
-    return v_out, spk, mask, steps, mac
 
 
 def stream_row_ctl(seeds: jax.Array, step_offsets: jax.Array,
@@ -391,12 +360,16 @@ def fused_seq(spikes: jax.Array, fw: FusedMacroWeights, v: jax.Array,
               v_reset: float = 0.0, v_lim: float = 8.0,
               use_snl: bool = True, ima_noise=None, snl_amp: float = 0.0,
               gate: bool = True, activity: jax.Array | None = None,
-              mac_telemetry: bool = True, seed=0, step_offset=0,
+              mac_telemetry: bool = True, seed=0,
               row_ctl: jax.Array | None = None):
     """A whole fused event sequence: spikes (T, ..., I), v (..., N),
     noise (T, ..., N) — or None for the in-kernel counter noise streams
-    (see ``fused_step``; this is the noisy-silicon serving path, with no
-    pre-drawn noise tensor and no composed-path fallback).
+    (this is the noisy-silicon serving path, with no pre-drawn noise tensor
+    and no composed-path fallback).  ``ima_noise`` (``ima.IMAKernelNoise``,
+    see ``fused_kernel_noise``) enables the in-kernel Fig. 7
+    conversion-error model; with ``noise=None`` the SNL stream is generated
+    in-kernel too (counter PRNG at ``snl_amp``), keyed on
+    ``(seed, t)``.
 
     ``row_ctl`` ((..., 3) int32, batch lead dims) carries per-row
     ``[seed, step_offset, row_id]`` noise-stream control for the
@@ -420,7 +393,7 @@ def fused_seq(spikes: jax.Array, fw: FusedMacroWeights, v: jax.Array,
         v_th1=v_th1, v_th2=v_th2, v_reset=v_reset, v_lim=v_lim,
         use_snl=use_snl, ima_noise=ima_noise, snl_amp=snl_amp, gate=gate,
         activity=activity, mac_telemetry=mac_telemetry, seed=seed,
-        step_offset=step_offset, row_ctl=row_ctl)
+        row_ctl=row_ctl)
     return v_out, spk, mask, steps, mac
 
 
@@ -469,7 +442,7 @@ def fused_multi_seq(spikes: jax.Array, stack, vs, noises=None, *, ks,
                     v_reset: float = 0.0, v_lim: float = 8.0,
                     use_snl: bool = True, ima_noise=None,
                     snl_amp: float = 0.0, gate: bool = True,
-                    tile_shapes=None, seeds=None, step_offset=0):
+                    tile_shapes=None, seeds=None):
     """A whole event sequence through L stacked KWN macro layers, fused.
 
     spikes (T, ..., I), stack a ``pack_kwn_stack`` result, vs/noises
@@ -490,7 +463,7 @@ def fused_multi_seq(spikes: jax.Array, stack, vs, noises=None, *, ks,
         vs, noises, ks=ks, drive_gain=drive_gain, beta=beta, v_th1=v_th1,
         v_th2=v_th2, v_reset=v_reset, v_lim=v_lim, use_snl=use_snl,
         ima_noise=ima_noise, snl_amp=snl_amp, gate=gate,
-        tile_shapes=tile_shapes, seeds=seeds, step_offset=step_offset)
+        tile_shapes=tile_shapes, seeds=seeds)
 
 
 def fused_seq_vjp(spikes: jax.Array, w: jax.Array, scale: jax.Array,

@@ -4,10 +4,10 @@ tiled over a virtual macro grid and batched over time.
 The paper's efficiency story (0.8 pJ/SOP, -30 % IMA latency, 10x LIF latency)
 comes from never leaving the macro: the analog MAC result stays on the RBLs,
 the IMA converts it in place, the KWN controller gates which LIF updates run.
-The composed kernel chain (``ternary_mac`` -> ``nlq_lut`` -> ``kwn_topk`` ->
-``lif_step``) round-trips every intermediate through HBM — the exact
-anti-pattern event-driven CIM accelerators exist to avoid.  This kernel is the
-TPU-native equivalent of staying inside the macro: one grid step per
+A chain of separate MAC, ramp, top-K and LIF kernels would round-trip every
+intermediate through HBM — the exact anti-pattern event-driven CIM
+accelerators exist to avoid.  This kernel is the TPU-native equivalent of
+staying inside the macro: one grid step per
 (row-tile, time-step, col-tile, K-tile) performs
 
   1. twin-cell ternary MAC (int8 MSB/LSB planes decoded in VMEM, MXU f32
@@ -468,7 +468,7 @@ def _lane_prefix_count(flags: jax.Array) -> jax.Array:
 
 
 def _kwn_sweep(codes: jax.Array, k: int, n_codes: int, bounded: bool = False):
-    """Descending-ramp priority-encoded top-K (same algorithm as kwn_topk).
+    """Descending-ramp priority-encoded top-K (``ref.kwn_topk_ref``).
 
     ``bounded=True`` is the activity-gated variant: the sweep starts at the
     highest code actually present in the tile and exits once every row has
@@ -831,8 +831,9 @@ def fused_macro_seq(x: jax.Array, msb: jax.Array, lsb: jax.Array,
                  SuperSpike derivative and the saturation gradient gate are
                  both functions of it.  KWN mode only.
     seed:        traced int32 scalar keying both noise streams.
-    step_offset: traced int32 added to the grid time index (lets the
-                 per-step launch cadence keep the seq-identical stream).
+    step_offset: traced int32 added to the grid time index, so a launch
+                 that starts mid-stream (T = 1 launches with the membrane
+                 carried between them) draws the one-launch stream.
     row_ctl:     optional (M, 3) int32 per-row stream control
                  ``[seed, step_offset, row_id]``.  When present it
                  *replaces* the scalar ``seed``/``step_offset`` and the
@@ -1071,7 +1072,6 @@ def _multi_seq_kwn_kernel(*refs, specs, ratio, bm, n_i, n_codes, beta,
 
     i, t = pl.program_id(0), pl.program_id(1)
     row0 = i * bm
-    step = ctl_ref[0, n_layers] + t
 
     @pl.when(t == 0)
     def _load_membranes():
@@ -1116,13 +1116,13 @@ def _multi_seq_kwn_kernel(*refs, specs, ratio, bm, n_i, n_codes, beta,
         mac = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, -1)
         codes = _ramp_codes(mac, bounds_ref)
         if ima_noise is not None:
-            codes = _ima_noisy_codes(codes, mac, seed, step, row0=row0,
+            codes = _ima_noisy_codes(codes, mac, seed, t, row0=row0,
                                      per_branch=spec.n, logical_n=spec.n,
                                      ima_noise=ima_noise, n_codes=n_codes)
         maskf, steps = _kwn_sweep(codes, spec.k, n_codes, bounded=gated)
         recon = _lut_reconstruct(codes, levels_ref)
         drive = recon * scale_ref[...] * maskf * drive_gain
-        nz = _lif_noise(noise_refs[li], (bm, spec.n), seed, step, row0=row0,
+        nz = _lif_noise(noise_refs[li], (bm, spec.n), seed, t, row0=row0,
                         logical_n=spec.n, snl_amp=snl_amp, use_snl=use_snl)
         v_new, spike, _ = _lif_update(
             v_refs[li][...], drive, maskf, nz, beta=beta, v_th1=v_th1,
@@ -1164,7 +1164,8 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
     activity: (T, M/bm, K0/bk0) int32 layer-0 occupancy map when
              ``gated`` (scalar-prefetched).  Deeper layers gate on the
              in-kernel winner sets — no host map exists for them.
-    ctl:     (1, L+1) int32: per-layer counter seeds + the step offset.
+    ctl:     (1, L) int32 per-layer counter seeds (the counter's step word
+             is the grid time index).
     specs:   tuple of ``LayerSpec`` (static per-layer geometry).
 
     Returns (v_outs (per-layer (M, n_l)), spikes (T, M, n_L) — the FINAL
@@ -1187,11 +1188,11 @@ def fused_macro_multi_seq(x: jax.Array, planes, v0s, noises=None,
     const_spec = lambda shape: pl.BlockSpec(
         shape, lambda i, t, *_: (0,) * len(shape))
     if ctl is None:
-        ctl = jnp.zeros((1, n_layers + 1), jnp.int32)
+        ctl = jnp.zeros((1, n_layers), jnp.int32)
 
     in_specs = [
         pl.BlockSpec((1, bm, kdim), lambda i, t, *_: (t, i, 0)),      # x
-        const_spec((1, n_layers + 1)),                                # ctl
+        const_spec((1, n_layers)),                                    # ctl
     ]
     inputs = [x.astype(jnp.int8), ctl.astype(jnp.int32)]
     for spec, (msb, lsb, bounds, levels, scale) in zip(specs, planes):

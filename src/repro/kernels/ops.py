@@ -17,10 +17,6 @@ import jax.numpy as jnp
 from repro.core import ternary as _ternary
 from repro.kernels import fused_macro as _fused
 from repro.kernels import fused_macro_grad as _fused_grad
-from repro.kernels import kwn_topk as _kwn
-from repro.kernels import lif_step as _lif
-from repro.kernels import nlq_lut as _nlq
-from repro.kernels import ternary_mac as _tmac
 
 
 def interpret_mode() -> bool:
@@ -31,69 +27,6 @@ def interpret_mode() -> bool:
     run in the Pallas interpreter.
     """
     return jax.default_backend() != "tpu"
-
-
-def _pad_to(x: jax.Array, axis: int, mult: int):
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
-        return x, size
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths), size
-
-
-def ternary_mac(x: jax.Array, msb: jax.Array, lsb: jax.Array,
-                ratio: float = 2.0, bm: int | None = None,
-                bn: int | None = None, bk: int | None = None) -> jax.Array:
-    """Batched ternary MAC; x may have leading batch dims. Pads to tiles."""
-    lead = x.shape[:-1]
-    xm = x.reshape(-1, x.shape[-1])
-    bm_, bn_, bk_ = (bm or min(128, _ceil_mult(xm.shape[0], 8)),
-                     bn or 128, bk or 256)
-    bm_ = min(bm_, 128)
-    xm, m0 = _pad_to(xm, 0, bm_)
-    xm, k0 = _pad_to(xm, 1, bk_)
-    msb_p, _ = _pad_to(msb, 0, bk_)
-    msb_p, n0 = _pad_to(msb_p, 1, bn_)
-    lsb_p, _ = _pad_to(lsb, 0, bk_)
-    lsb_p, _ = _pad_to(lsb_p, 1, bn_)
-    out = _tmac.ternary_mac(xm.astype(jnp.int8), msb_p.astype(jnp.int8),
-                            lsb_p.astype(jnp.int8), bm=bm_, bn=bn_, bk=bk_,
-                            ratio=ratio, interpret=interpret_mode())
-    return out[:m0, :n0].reshape(*lead, n0)
-
-
-def _ceil_mult(n: int, m: int) -> int:
-    return max(m, ((n + m - 1) // m) * m)
-
-
-def kwn_topk(mac: jax.Array, boundaries: jax.Array, k: int):
-    """Batched KWN; mac (..., N) -> (mask (..., N), adc_steps (...,))."""
-    lead = mac.shape[:-1]
-    xm = mac.reshape(-1, mac.shape[-1]).astype(jnp.float32)
-    bm = min(128, _ceil_mult(xm.shape[0], 8))
-    xm, m0 = _pad_to(xm, 0, bm)
-    mask, steps = _kwn.kwn_topk(xm, boundaries.astype(jnp.float32), k, bm=bm,
-                                interpret=interpret_mode())
-    return (mask[:m0].reshape(*lead, mac.shape[-1]),
-            steps[:m0, 0].reshape(lead))
-
-
-def lif_step(v, drive, mask, noise, **params):
-    """Batched fused LIF; all (..., N)."""
-    lead = v.shape[:-1]
-    n = v.shape[-1]
-    flat = [a.reshape(-1, n).astype(jnp.float32) for a in (v, drive, mask, noise)]
-    bm = min(256, _ceil_mult(flat[0].shape[0], 8))
-    padded = []
-    m0 = flat[0].shape[0]
-    for a in flat:
-        a, _ = _pad_to(a, 0, bm)
-        padded.append(a)
-    v_out, spikes = _lif.lif_step_fused(*padded, bm=bm,
-                                        interpret=interpret_mode(), **params)
-    return v_out[:m0].reshape(*lead, n), spikes[:m0].reshape(*lead, n)
 
 
 def _pad_cols(a, n: int, n_pad: int, n_branches: int):
@@ -287,38 +220,6 @@ def fused_macro_seq(x, msb, lsb, boundaries, levels, scale, v, noise=None,
     return ret
 
 
-def fused_macro_step(x, msb, lsb, boundaries, levels, scale, v, noise=None,
-                     w_dend=None, *, mode: str = "kwn", k: int = 12,
-                     ratio: float = 2.0, drive_gain: float = 1.0,
-                     beta: float = 0.9, v_th1: float = 1.0, v_th2: float = 0.6,
-                     v_reset: float = 0.0, v_lim: float = 8.0,
-                     use_snl: bool = True, bm: int | None = None,
-                     bk: int | None = None, bn: int | None = None,
-                     ima_noise=None, snl_amp: float = 0.0,
-                     gate: bool = True, mac_telemetry: bool = True, seed=0,
-                     step_offset=0):
-    """Batched fused macro step; x (..., K), v/noise (..., N).
-
-    The T=1 degenerate of ``fused_macro_seq`` (one kernel launch per time
-    step), including its activity gating (``gate``) and optional raw-MAC
-    telemetry (``mac_telemetry``).  With ``ima_noise``, pass the scan
-    index as ``step_offset`` so a per-step cadence draws the same stream
-    as the one-launch sequence.
-    Returns (mac (..., NC) or None, v_out, spikes, mask (..., N),
-    adc_steps (...,)).
-    """
-    mac, v_out, spikes, mask, steps = fused_macro_seq(
-        x[None], msb, lsb, boundaries, levels, scale, v,
-        None if noise is None else noise[None], w_dend,
-        mode=mode, k=k, ratio=ratio, drive_gain=drive_gain, beta=beta,
-        v_th1=v_th1, v_th2=v_th2, v_reset=v_reset, v_lim=v_lim,
-        use_snl=use_snl, bm=bm, bk=bk, bn=bn, ima_noise=ima_noise,
-        snl_amp=snl_amp, gate=gate, mac_telemetry=mac_telemetry, seed=seed,
-        step_offset=step_offset)
-    return (None if mac is None else mac[0], v_out, spikes[0], mask[0],
-            steps[0])
-
-
 class MultiSeqOut(NamedTuple):
     """Outputs of the stacked multi-layer fused sequence.
 
@@ -348,7 +249,7 @@ def fused_macro_multi_seq(x, stack, vs, noises=None, *, ks,
                           v_lim: float = 8.0, use_snl: bool = True,
                           bm: int | None = None, tile_shapes=None,
                           ima_noise=None, snl_amp: float = 0.0,
-                          gate: bool = True, seeds=None, step_offset=0):
+                          gate: bool = True, seeds=None):
     """L stacked KWN macro layers, batched: x (T, ..., K0), one launch.
 
     stack:  per-layer (msb, lsb, boundaries, levels, scale) operand tuples
@@ -428,9 +329,7 @@ def fused_macro_multi_seq(x, stack, vs, noises=None, *, ks,
             for nz, w in zip(noises, widths))
     if seeds is None:
         seeds = jnp.zeros((n_layers,), jnp.int32)
-    ctl = jnp.concatenate([
-        jnp.asarray(seeds, jnp.int32).reshape(-1),
-        jnp.asarray(step_offset, jnp.int32).reshape(1)]).reshape(1, -1)
+    ctl = jnp.asarray(seeds, jnp.int32).reshape(1, -1)
     planes = [tuple(s[:5]) for s in stack]
     if plan0.k_pad != kdim:              # zero K rows are MAC-neutral
         msb0, lsb0 = planes[0][0], planes[0][1]
@@ -601,16 +500,3 @@ def _seq_vjp_bwd(spec, res, cts):
 
 
 fused_macro_seq_vjp.defvjp(_seq_vjp_fwd, _seq_vjp_bwd)
-
-
-def nlq_convert(x, boundaries, levels):
-    """Batched NLQ; x (..., N) -> (codes, reconstruction)."""
-    lead = x.shape[:-1]
-    n = x.shape[-1]
-    xm = x.reshape(-1, n).astype(jnp.float32)
-    bm = min(256, _ceil_mult(xm.shape[0], 8))
-    xm, m0 = _pad_to(xm, 0, bm)
-    codes, y = _nlq.nlq_convert(xm, boundaries.astype(jnp.float32),
-                                levels.astype(jnp.float32), bm=bm,
-                                interpret=interpret_mode())
-    return codes[:m0].reshape(*lead, n), y[:m0].reshape(*lead, n)

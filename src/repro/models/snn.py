@@ -201,32 +201,31 @@ def forward_silicon(p, events, cfg: SNNConfig, key: jax.Array,
 
     ``fused`` selects the execution path:
 
-    * ``False`` — the composed stage chain (HBM-visible intermediates);
+    * ``False`` — the composed stage chain (HBM-visible intermediates), the
+      model-level oracle of the kernel path;
     * ``True`` / ``"seq"`` — the time-major fused kernel: the *whole* event
       sequence runs in one Pallas launch (MAC -> IMA -> mode head -> LIF in
       one VMEM pass per step, LIF membrane carried in VMEM across T), with
       any virtual-macro tiling the layer shape needs picked automatically
-      by the kernel-side tile planner;
-    * ``"step"`` — the PR 1 behaviour: one fused kernel launch per scan
-      step (kept for launch-overhead benchmarking).
+      by the kernel-side tile planner.
 
-    All fused variants are bitwise-equal to the composed path at f32 in KWN
-    mode; in NLD mode they additionally quantize the branch weights onto
-    the twin-cell grid (the silicon storage format), so accuracies can
-    differ slightly from the float-weight composed path.
+    Any other value raises ``ValueError``.  The fused path is bitwise-equal
+    to the composed path at f32 in KWN mode; in NLD mode it additionally
+    quantizes the branch weights onto the twin-cell grid (the silicon
+    storage format), so accuracies can differ slightly from the float-weight
+    composed path.
 
-    With ``noise`` (the Fig. 7 ``IMANoiseModel``), the fused paths stay
+    With ``noise`` (the Fig. 7 ``IMANoiseModel``), the fused path stays
     fused: the per-step per-column conversion-error draws — and the SNL
     sign noise — are generated *inside* the kernel by the counter PRNG,
     keyed on a seed derived from ``key``, with no pre-drawn noise tensor
-    and no composed-path fallback.  Noisy ``"step"`` and ``"seq"`` draw the
-    identical stream (the scan index is the counter's step word), and both
-    are bitwise-equal to ``kernels.ref.fused_macro_seq_ref`` with the same
-    parameters.  The noisy *composed* path keeps its historical
-    ``jax.random``/PRBS draws, so noisy composed and noisy fused are
-    statistically — not bitwise — equivalent.
+    and no composed-path fallback; it is bitwise-equal to
+    ``kernels.ref.fused_macro_seq_ref`` with the same parameters.  The
+    noisy *composed* path keeps its historical ``jax.random``/PRBS draws,
+    so noisy composed and noisy fused are statistically — not bitwise —
+    equivalent.
 
-    The fused paths are *activity-gated*: the occupancy plan of the event
+    The fused path is *activity-gated*: the occupancy plan of the event
     sequence is built once per sequence (``macro.plan_activity``) and the
     kernel skips MAC work for all-zero (step, row-tile, K-tile) blocks and
     bounds the KWN ramp sweep — output bits are unchanged, so gating has
@@ -237,11 +236,11 @@ def forward_silicon(p, events, cfg: SNNConfig, key: jax.Array,
     not raw MACs, and that write was the fused step's largest dead output.
 
     Stacked configs (``cfg.hidden_layers`` with more than one width) route
-    every ``fused`` choice through the multi-layer machinery: ``"seq"`` /
-    ``"step"`` use the stacked kernel (one launch chains all layers, the
-    inter-layer ternary spike tensor never leaves the chip, layer l's KWN
-    winner set is layer l+1's activity plan), ``False`` composes the stage
-    chain per layer.  All three agree bitwise in KWN mode; NLD stacks and
+    both ``fused`` choices through the multi-layer machinery: ``"seq"``
+    uses the stacked kernel (one launch chains all layers, the inter-layer
+    ternary spike tensor never leaves the chip, layer l's KWN winner set is
+    layer l+1's activity plan), ``False`` composes the stage chain per
+    layer.  The two agree bitwise in KWN mode; NLD stacks and
     ``mac_telemetry=True`` on stacks are unsupported (ValueError).
 
     Returns (logits, telemetry) where telemetry carries adc_steps per time
@@ -256,6 +255,9 @@ def forward_silicon(p, events, cfg: SNNConfig, key: jax.Array,
     use_snl = cfg.use_snl if use_snl is None else use_snl
     if fused is True:
         fused = "seq"
+    if fused is not False and fused != "seq":
+        raise ValueError(f"unknown fused={fused!r}; expected False, True, "
+                         f"or 'seq'")
     b, t_steps = events.shape[0], events.shape[1]
     multi = len(cfg.layer_widths) > 1
     if multi and mode != "kwn":
@@ -268,26 +270,17 @@ def forward_silicon(p, events, cfg: SNNConfig, key: jax.Array,
                               noise_amp=cfg.noise_amp if use_snl else 0.0)
     if multi:
         ks = cfg.k_layers or (k,) * len(cfg.layer_widths)
-        if fused in ("seq", "step"):
+        if fused == "seq":
             if mac_telemetry:
                 raise ValueError("mac_telemetry is single-layer only: the "
                                  "stacked kernel never writes MACs to HBM")
             return _forward_silicon_fused_multi(p, events, cfg, ks, use_snl,
-                                                mcfg, lif_p, key, fused)
-        if fused is not False:
-            raise ValueError(f"unknown fused={fused!r}; expected False, "
-                             f"True, 'step', or 'seq'")
+                                                mcfg, lif_p, key)
         return _forward_silicon_composed_multi(p, events, cfg, ks, use_snl,
                                                mcfg, lif_p, key, noise)
     if fused == "seq":
         return _forward_silicon_fused_seq(p, events, cfg, mode, k, use_snl,
                                           mcfg, lif_p, key, mac_telemetry)
-    if fused == "step":
-        return _forward_silicon_fused(p, events, cfg, mode, k, use_snl, mcfg,
-                                      lif_p, key, mac_telemetry)
-    if fused is not False:
-        raise ValueError(f"unknown fused={fused!r}; expected False, True, "
-                         f"'step', or 'seq'")
     if mode == "kwn":
         w_int, scale = _quantized_weights(p, cfg)
         nlq = _nlq_cb(cfg)
@@ -413,78 +406,6 @@ def _noise_seed(key: jax.Array) -> jax.Array:
                               dtype=jnp.int32)
 
 
-def _forward_silicon_fused(p, events, cfg: SNNConfig, mode: str, k: int,
-                           use_snl: bool, mcfg, lif_p, key,
-                           mac_telemetry: bool = False):
-    """Per-step fused inference scan body.
-
-    Mirrors the composed ``forward_silicon`` step exactly in the clean case
-    (same PRBS state threading, same telemetry), one fused Pallas kernel
-    per time step.  With ``mcfg.ima_noise`` the per-step launches pass the
-    scan index as the counter step word, so the stream — and therefore
-    every spike — is bitwise-identical to the one-launch ``seq`` path.
-    Kept for launch-overhead benchmarking; the serving default is the
-    time-major ``_forward_silicon_fused_seq``.  Each per-step launch gates
-    on its own step's activity map (the T=1 slice of the sequence plan),
-    so the reported skipped-block ratio matches the seq path exactly.
-    """
-    b = events.shape[0]
-    fw = _pack_fused(p, cfg, mode, mcfg)
-    snl_active = use_snl and mode == "kwn"
-    noisy = mcfg.ima_noise is not None
-    ima_kn = macro_lib.fused_kernel_noise(fw, mcfg)
-    seed = _noise_seed(key) if noisy else jnp.int32(0)
-
-    def step(carry, inp):
-        v, prbs_state, spk_acc, tele = carry
-        ev, t = inp
-        if noisy:
-            nz = None           # SNL noise comes from the in-kernel counter
-        elif snl_active:
-            prbs_state, nz = prbs_lib.prbs_noise(prbs_state, v.shape,
-                                                 lif_p.noise_amp)
-        else:
-            nz = jnp.zeros_like(v)
-        v, s, mask, steps, _ = macro_lib.fused_step(
-            ev, fw, v, nz, k=k, drive_gain=cfg.drive_gain, beta=cfg.beta,
-            v_th1=cfg.v_th1, v_th2=cfg.v_th2, v_reset=lif_p.v_reset,
-            v_lim=lif_lib.vmem_limit(lif_p.vmem_bits),
-            use_snl=snl_active, ima_noise=ima_kn,
-            snl_amp=lif_p.noise_amp if (noisy and snl_active) else 0.0,
-            mac_telemetry=mac_telemetry, seed=seed, step_offset=t)
-        n_upd = float(k if mode == "kwn" else cfg.n_hidden)
-        tele = {
-            "adc_steps": tele["adc_steps"] + steps.astype(jnp.float32),
-            "lif_updates": tele["lif_updates"] + n_upd,
-            "sops": tele["sops"] + jnp.sum(jnp.abs(ev), -1) * cfg.n_hidden,
-        }
-        return (v, prbs_state, spk_acc + s, tele), None
-
-    tele0 = {"adc_steps": jnp.zeros((b,)), "lif_updates": jnp.zeros((b,)),
-             "sops": jnp.zeros((b,))}
-    st0 = lif_lib.lif_init((b, cfg.n_hidden))
-    init = (st0.v_mem, st0.prbs_state, jnp.zeros((b, cfg.n_hidden)), tele0)
-    t_steps = events.shape[1]
-    (_, _, counts, tele), _ = jax.lax.scan(
-        step, init, (jnp.moveaxis(events, 1, 0),
-                     jnp.arange(t_steps, dtype=jnp.int32)))
-    logits = (counts / t_steps) @ p["w_out"]
-    tele = jax.tree.map(lambda x: x / t_steps, tele)
-    tele["skipped_block_ratio"] = _skipped_block_ratio(events, fw, cfg)
-    return logits, tele
-
-
-def _skipped_block_ratio(events, fw, cfg: SNNConfig) -> jax.Array:
-    """Fraction of (step, row-tile, K-tile) MAC blocks gating elides,
-    broadcast per request (the plan is a batch-level property — requests
-    share row tiles)."""
-    act = macro_lib.plan_activity(jnp.moveaxis(events, 1, 0), fw,
-                                  cfg.n_hidden)
-    # clip: f32 mean of an all-ones map can land a ULP past 1.0
-    ratio = jnp.clip(1.0 - jnp.mean(act.astype(jnp.float32)), 0.0, 1.0)
-    return jnp.full((events.shape[0],), ratio)
-
-
 def _forward_silicon_fused_seq(p, events, cfg: SNNConfig, mode: str, k: int,
                                use_snl: bool, mcfg, lif_p, key,
                                mac_telemetry: bool = False):
@@ -492,12 +413,11 @@ def _forward_silicon_fused_seq(p, events, cfg: SNNConfig, mode: str, k: int,
 
     The T axis is folded into the Pallas grid (``macro.fused_seq``), so the
     LIF membrane never leaves VMEM between steps and the weight planes are
-    staged once per sequence instead of once per step — the serving
-    engine's dominant launch overhead.  In the clean case PRBS noise is
-    pre-drawn with the exact LFSR sequence the per-step path threads
-    through its scan, and the per-step output stacks are left-folded in
-    scan order, so logits and telemetry stay bitwise-equal to the composed
-    and per-step paths.  In the noisy case (``mcfg.ima_noise``) *nothing*
+    staged once per sequence instead of once per step.  In the clean case
+    PRBS noise is pre-drawn with the exact LFSR sequence the composed path
+    threads through its scan, and the per-step output stacks are
+    left-folded in scan order, so logits and telemetry stay bitwise-equal
+    to the composed path.  In the noisy case (``mcfg.ima_noise``) *nothing*
     is pre-drawn: both the IMA conversion error and the SNL sign noise
     come from the in-kernel counter PRNG, and the launch streams only the
     events themselves.
@@ -903,17 +823,14 @@ def _noise_seeds(key: jax.Array, n_layers: int) -> jax.Array:
 
 
 def _forward_silicon_fused_multi(p, events, cfg: SNNConfig, ks, use_snl,
-                                 mcfg, lif_p, key, cadence: str):
+                                 mcfg, lif_p, key):
     """Stacked fused inference: L macro layers chained on-chip.
 
-    ``cadence="seq"`` runs the whole sequence and the whole stack in ONE
-    Pallas launch (``macro.fused_multi_seq``): per-layer membranes live in
-    VMEM across time steps and the inter-layer ternary spike tensors never
-    reach HBM — layer l's KWN winner set IS layer l+1's activity plan,
-    evaluated in-kernel (only layer 0 gates on the host occupancy map).
-    ``cadence="step"`` launches the stack once per time step (launch-
-    overhead benchmarking); both draw identical noise streams and are
-    bitwise-equal.
+    Runs the whole sequence and the whole stack in ONE Pallas launch
+    (``macro.fused_multi_seq``): per-layer membranes live in VMEM across
+    time steps and the inter-layer ternary spike tensors never reach HBM —
+    layer l's KWN winner set IS layer l+1's activity plan, evaluated
+    in-kernel (only layer 0 gates on the host occupancy map).
 
     Hidden-layer activity is reported through telemetry only (per-layer
     spike counts for SOPs, per-layer occupancy counters for the
@@ -933,14 +850,12 @@ def _forward_silicon_fused_multi(p, events, cfg: SNNConfig, ks, use_snl,
     if noisy or not snl_active:
         noises = None if noisy else [jnp.zeros((t_steps, b, w))
                                      for w in widths]
-        prbs0 = None
     else:
         # pre-draw each layer's PRBS stream exactly as the composed path's
         # per-layer LIF states thread it (bitwise parity in the clean case)
-        noises, prbs0 = [], []
+        noises = []
         for w in widths:
             st = lif_lib.lif_init((b, w))
-            prbs0.append(st.prbs_state)
 
             def draw(s, _, w=w):
                 s, nz = prbs_lib.prbs_noise(s, (b, w), lif_p.noise_amp)
@@ -948,63 +863,28 @@ def _forward_silicon_fused_multi(p, events, cfg: SNNConfig, ks, use_snl,
 
             _, nz_t = jax.lax.scan(draw, st.prbs_state, None, length=t_steps)
             noises.append(nz_t)
-    kw = dict(ks=tuple(ks), drive_gain=cfg.drive_gain, beta=cfg.beta,
-              v_th1=cfg.v_th1, v_th2=cfg.v_th2, v_reset=lif_p.v_reset,
-              v_lim=lif_lib.vmem_limit(lif_p.vmem_bits), use_snl=snl_active,
-              ima_noise=ima_kn,
-              snl_amp=lif_p.noise_amp if (noisy and snl_active) else 0.0,
-              seeds=seeds)
-    if cadence == "seq":
-        out = macro_lib.fused_multi_seq(ev_t, stack, v0s, noises, **kw)
-        spk_t = out.spikes                                  # (T, B, N_last)
-        steps_t = [s for s in out.steps]                    # L x (T, B)
-        cnts_t = [c for c in out.spike_counts]              # L x (T, B)
-        occ_total = sum(jnp.sum(o) for o in out.occupancy)
-        total_blocks = out.total_blocks
-    else:
-        spk_steps, steps_steps, cnts_steps = [], [], []
-        occ_total, total_blocks = jnp.int32(0), 0
-        vs, prbs = v0s, prbs0
-        for t in range(t_steps):
-            if noises is None:
-                nz = None
-            elif prbs is None:
-                nz = [n[t:t + 1] for n in noises]
-            else:
-                nz, new_prbs = [], []
-                for li, w in enumerate(widths):
-                    s, nz_l = prbs_lib.prbs_noise(prbs[li], (b, w),
-                                                  lif_p.noise_amp)
-                    new_prbs.append(s)
-                    nz.append(nz_l[None])
-                prbs = new_prbs
-            out = macro_lib.fused_multi_seq(ev_t[t:t + 1], stack, vs, nz,
-                                            step_offset=t, **kw)
-            vs = list(out.v_outs)
-            spk_steps.append(out.spikes[0])
-            steps_steps.append([s[0] for s in out.steps])
-            cnts_steps.append([c[0] for c in out.spike_counts])
-            occ_total = occ_total + sum(jnp.sum(o) for o in out.occupancy)
-            total_blocks += out.total_blocks
-        spk_t = jnp.stack(spk_steps)
-        steps_t = [jnp.stack([s[li] for s in steps_steps])
-                   for li in range(n_layers)]
-        cnts_t = [jnp.stack([c[li] for c in cnts_steps])
-                  for li in range(n_layers)]
-    counts = jnp.sum(spk_t, axis=0)
+    out = macro_lib.fused_multi_seq(
+        ev_t, stack, v0s, noises, ks=tuple(ks), drive_gain=cfg.drive_gain,
+        beta=cfg.beta, v_th1=cfg.v_th1, v_th2=cfg.v_th2,
+        v_reset=lif_p.v_reset, v_lim=lif_lib.vmem_limit(lif_p.vmem_bits),
+        use_snl=snl_active, ima_noise=ima_kn,
+        snl_amp=lif_p.noise_amp if (noisy and snl_active) else 0.0,
+        seeds=seeds)
+    counts = jnp.sum(out.spikes, axis=0)
     logits = (counts / t_steps) @ p["w_out"]
-    adc = sum(jnp.sum(s.astype(jnp.float32), axis=0) for s in steps_t)
+    adc = sum(jnp.sum(s.astype(jnp.float32), axis=0) for s in out.steps)
     sops = jnp.sum(jnp.sum(jnp.abs(ev_t), axis=-1).astype(jnp.float32),
                    axis=0) * widths[0]
     for li in range(1, n_layers):
-        sops = sops + jnp.sum(cnts_t[li - 1], axis=0) * widths[li]
+        sops = sops + jnp.sum(out.spike_counts[li - 1], axis=0) * widths[li]
+    occ_total = sum(jnp.sum(o) for o in out.occupancy)
     tele = {
         "adc_steps": adc / t_steps,
         "lif_updates": jnp.full((b,), float(sum(ks))),
         "sops": sops / t_steps,
         "skipped_block_ratio": jnp.full(
             (b,), jnp.clip(1.0 - occ_total.astype(jnp.float32)
-                           / total_blocks, 0.0, 1.0)),
+                           / out.total_blocks, 0.0, 1.0)),
     }
     return logits, tele
 

@@ -118,13 +118,15 @@ class EventRequest:
 
 @functools.lru_cache(maxsize=None)
 def _legacy_forward(cfg, fused: str, noise):
-    """One jitted drain-path forward per (config, cadence, noise model).
+    """One jitted drain-path forward per (config, noise model).
 
-    Module-level cache so every engine instance over the same config
-    shares one compiled executable (a per-instance ``jax.jit(lambda ...)``
-    would recompile per engine — ruinous for the serve benchmarks' warm
-    trials).  ``cfg`` (frozen dataclass) and ``noise`` (NamedTuple) are
-    hashable, so they can key the cache and close over the trace.
+    ``fused`` is handed to ``forward_silicon``; the engine always passes
+    ``"seq"``, the kernel path.  Module-level cache so every engine
+    instance over the same config shares one compiled executable (a
+    per-instance ``jax.jit(lambda ...)`` would recompile per engine —
+    ruinous for the serve benchmarks' warm trials).  ``cfg`` (frozen
+    dataclass) and ``noise`` (NamedTuple) are hashable, so they can key the
+    cache and close over the trace.
     """
     return jax.jit(lambda p, ev, key: snn_lib.forward_silicon(
         p, ev, cfg, key, fused=fused, noise=noise))
@@ -136,7 +138,7 @@ class SNNEventEngine:
     drain-the-queue batches.
 
     **Continuous path** (``continuous=True``, auto-selected for
-    time-major single-layer configs).  The engine keeps ``batch_slots``
+    single-layer configs).  The engine keeps ``batch_slots``
     persistent serving slots whose LIF membrane — the SNN analog of an LM
     engine's KV cache — lives on device in a
     ``snn.SiliconStreamState`` and is carried across rounds.  Each round
@@ -164,9 +166,9 @@ class SNNEventEngine:
     per row-*tile*, shared across co-resident slots — survives batching.
     Results are unchanged either way; only the work moves.
 
-    **Legacy path** (``continuous=False``, and the automatic fallback for
-    ``time_major=False`` or multi-layer stacks).  One jitted
-    ``forward_silicon(fused=...)`` call per fixed-size batch of whole
+    **Legacy path** (``continuous=False``, and the automatic choice for
+    multi-layer stacks).  One jitted
+    ``forward_silicon(fused="seq")`` call per fixed-size batch of whole
     sequences, padded to ``batch_slots`` rows; batches are bucketed by
     stream length (one jit entry per distinct T served).  ``noise`` draws
     then come from the engine's per-batch key stream, as before.
@@ -201,7 +203,7 @@ class SNNEventEngine:
     """
 
     def __init__(self, cfg: snn_lib.SNNConfig, params, batch_slots: int = 64,
-                 seed: int = 0, time_major: bool = True, noise=None,
+                 seed: int = 0, noise=None,
                  pack_by_density: bool = True,
                  continuous: bool | None = None, round_steps: int = 8,
                  max_pending: int | None = None, preemptive: bool = True,
@@ -211,7 +213,6 @@ class SNNEventEngine:
         self.cfg = cfg
         self.params = params
         self.b = batch_slots
-        self.time_major = time_major
         self.noise = noise
         self.pack_by_density = pack_by_density
         self.pending: list[EventRequest] = []
@@ -221,15 +222,14 @@ class SNNEventEngine:
         self._submitted = 0
         self._key = jax.random.PRNGKey(seed)
         self._base_key = jax.random.PRNGKey(seed)
-        self._fused = "seq" if time_major else "step"
-        supported = time_major and len(cfg.layer_widths) == 1
+        single_layer = len(cfg.layer_widths) == 1
         if continuous is None:
-            continuous = supported
-        elif continuous and not supported:
+            continuous = single_layer
+        elif continuous and not single_layer:
             raise ValueError(
-                "continuous batching needs the time-major fused kernel and "
-                "a single-layer config; pass continuous=False (or leave it "
-                "None to auto-select) for per-step cadence or stacks")
+                "continuous batching needs a single-layer config; pass "
+                "continuous=False (or leave it None to auto-select) for "
+                "stacks")
         self.continuous = continuous
         self.round_steps = round_steps
         self.max_pending = max_pending
@@ -417,7 +417,7 @@ class SNNEventEngine:
             tr.end(stage, args={"bytes": ev.nbytes})
         with tr.span("launch", track="scheduler"):
             self._key, sub = jax.random.split(self._key)
-            fwd = _legacy_forward(self.cfg, self._fused, self.noise)
+            fwd = _legacy_forward(self.cfg, "seq", self.noise)
             logits, tele = fwd(self.params, ev, sub)
         with tr.span("wait", track="scheduler"):
             jax.block_until_ready((logits, tele))

@@ -6,10 +6,8 @@ Three pieces (see ``docs/TILE_PLANS.md`` for the full contract):
   ``(bm, bk, bn)`` tile plans keyed on (op, shape, density bucket, mode,
   device kind), consumed transparently by ``kernels.fused_macro.plan_tiles``
   with the PR 4 heuristic as the fallback.
-* ``repro.tune.measure``  — the bench timing loop (median-of-iters wall
-  time) and the bursty event-stream generator, shared with
-  ``benchmarks/bench_fused_macro.py`` so tuner medians and bench medians
-  are the same instrument.
+* ``repro.tune.measure``  — the tuner's timing loop (median-of-iters wall
+  time) and the bursty event-stream generator its candidates run on.
 * ``repro.tune.autotune`` — the search: enumerate candidate plans, prune
   with the roofline prior, measure each candidate's latency (and modeled
   kernel-energy pJ/SOP), pick the winner under the requested objective,

@@ -2,7 +2,7 @@
 bucket, mode, device kind).
 
 The cache is a single JSON file (default ``PLAN_CACHE_fused_macro.json`` at
-the repo root, next to ``BENCH_fused_macro.json``) written by the autotuner
+the repo root) written by the autotuner
 (``repro.tune.autotune`` / ``tools/tune_plans.py``) and consumed by
 ``kernels.fused_macro.plan_tiles`` as a lookup fast path in front of the
 lane-alignment heuristic.  Design constraints, in order:

@@ -1,13 +1,8 @@
-"""Shared measurement instruments: the bench timing loop + event streams.
+"""The autotuner's measurement instruments: a timing loop + event streams.
 
-``benchmarks/bench_fused_macro.py`` and the autotuner must agree on what a
-"median latency" is — a tuned plan picked under one stopwatch and gated
-under another would let clock-skew masquerade as a tuning win.  So the
-timing loop and the bursty event-stream generator live here, and the bench
-aliases them (``bench_fused_macro._time`` *is* ``measure.median_us``).
-
-Both functions are exactly the instruments the bench has carried since
-PR 4; moving them is a relocation, not a re-derivation.
+``median_us`` is the median-of-iters wall time of a jitted call, and
+``event_stream`` the DVS-like event stream every candidate plan is timed
+on; the autotuner picks and records plans under this one stopwatch.
 """
 
 from __future__ import annotations
@@ -21,10 +16,9 @@ import numpy as np
 from repro.obs import trace as obs_trace
 
 # Per-element event rate inside an active (burst) step of a DVS-like
-# stream.  Shared constant: the bench's density sweep and the tuner's
-# candidate measurements must synthesize the same temporal structure,
-# because the activity planner's skipped-block ratio (and therefore the
-# measured latency ordering of candidate plans) depends on it.
+# stream.  The tuner's candidate measurements depend on this temporal
+# structure, because the activity planner's skipped-block ratio (and
+# therefore the measured latency ordering of candidate plans) does.
 IN_BURST_DENSITY = 0.2
 
 
@@ -34,8 +28,8 @@ def median_us(fn, args, iters: int = 20, label: str | None = None) -> float:
 
     Each call emits one ``measure`` span on the ``measure`` track of the
     process-global tracer (when enabled), covering warmup + all timed
-    iterations and carrying the resulting median — so a traced bench or
-    autotune run (``--trace-out``) renders every candidate measurement
+    iterations and carrying the resulting median — so a traced autotune
+    run (``--trace-out``) renders every candidate measurement
     as its own block on the timeline.  ``label`` names the span
     (e.g. the autotuner's candidate tile plan); the measurement itself
     is unchanged.

@@ -37,7 +37,7 @@ SLOW_MODULES = {
 FAST_MODULES = {
     # the <60 s tier-1 smoke set: core semantics, golden regressions (incl.
     # the fused-kernel tiling/time-major invariance checks), roofline.
-    # Full composed-kernel parity (test_kernels, test_fused_macro*) lives
+    # Full per-stage kernel parity (test_kernels, test_fused_macro*) lives
     # in the default tier — it's worth real minutes, not smoke seconds.
     # test_ima_noise.py curates its own smoke subset with explicit
     # ``@pytest.mark.fast`` markers (one noisy-parity shape, seeded

@@ -1,7 +1,8 @@
-"""Fused macro-step kernel vs the composed ref.py oracle.
+"""Fused macro kernel vs the composed ref.py oracle.
 
 The fused kernel (MAC -> IMA ramp -> KWN/NLD head -> LIF in one Pallas
-kernel, interpret=True on CPU CI) must match ``ref.fused_macro_step_ref``
+kernel, interpret=True on CPU CI), run for one time step, must match
+``ref.fused_macro_step_ref``
 *bitwise* at f32 accumulation: the MAC partials are small exact integers and
 the head mirrors the oracle operation-for-operation.  The oracle is jitted so
 both sides get identical XLA arithmetic contraction (FMA) treatment.
@@ -11,8 +12,8 @@ NL-activation), odd shapes (n_in not a multiple of 256, n_out not a multiple
 of 128, batch not a multiple of 8), SNL on/off, multi-macro tiling (layers
 wider than 256x128 stay fused — no composed-path fallback), time-major
 sequences (T folded into the kernel grid, membrane carried in VMEM), and the
-model/serving layers built on top (forward_silicon(fused=True/"step"/"seq"),
-SNNEventEngine time-major batching).  The exhaustive shape sweeps live in
+model/serving layers built on top (forward_silicon(fused=True/"seq"),
+SNNEventEngine batching).  The exhaustive shape sweeps live in
 tests/test_fused_macro_properties.py.
 """
 
@@ -40,6 +41,17 @@ def _codebook(kind, bits=5, rng=24.0):
     if kind == "nlq":
         return ima_lib.nlq_codebook(bits, -rng, rng)
     return ima_lib.activation_codebook(bits, ima_lib.quadratic, -rng, rng)
+
+
+def _one_step(x, msb, lsb, boundaries, levels, scale, v, noise=None,
+              w_dend=None, **kw):
+    """One time step through the sequence kernel: a T = 1 launch with the
+    time axis indexed back off."""
+    mac, v_out, spk, mask, steps = ops.fused_macro_seq(
+        x[None], msb, lsb, boundaries, levels, scale, v,
+        None if noise is None else noise[None], w_dend, **kw)
+    return (None if mac is None else mac[0], v_out, spk[0], mask[0],
+            steps[0])
 
 
 def _ref_jit(**static):
@@ -74,8 +86,8 @@ class TestFusedKwnParity:
         noise = 0.05 * jnp.sign(jax.random.normal(keys[5], (m, n_out)))
         k = min(12, n_out)
         kw = dict(mode="kwn", k=k, drive_gain=0.25)
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, noise, **kw)
+        out = _one_step(x, msb, lsb, cb.boundaries, cb.levels, scale, v,
+                        noise, **kw)
         want = _ref_jit(**kw)(x, msb, lsb, cb.boundaries, cb.levels, scale,
                               v, noise)
         _assert_bitwise(out, want, n_out)
@@ -90,8 +102,8 @@ class TestFusedKwnParity:
         v = jax.random.normal(keys[4], (16, 128)) * 0.5
         noise = 0.05 * jnp.sign(jax.random.normal(keys[5], (16, 128)))
         kw = dict(mode="kwn", k=k, drive_gain=0.25)
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, noise, **kw)
+        out = _one_step(x, msb, lsb, cb.boundaries, cb.levels, scale, v,
+                        noise, **kw)
         want = _ref_jit(**kw)(x, msb, lsb, cb.boundaries, cb.levels, scale,
                               v, noise)
         _assert_bitwise(out, want, 128)
@@ -108,8 +120,8 @@ class TestFusedKwnParity:
         v = 0.8 * jnp.ones((16, 128))
         noise = 0.3 * jnp.sign(jax.random.normal(keys[5], (16, 128)))
         kw = dict(mode="kwn", k=12, drive_gain=0.25, use_snl=use_snl)
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, noise, **kw)
+        out = _one_step(x, msb, lsb, cb.boundaries, cb.levels, scale, v,
+                        noise, **kw)
         want = _ref_jit(**kw)(x, msb, lsb, cb.boundaries, cb.levels, scale,
                               v, noise)
         _assert_bitwise(out, want, 128)
@@ -122,13 +134,12 @@ class TestFusedKwnParity:
         scale = jnp.full((128,), 0.1)
         v = jax.random.normal(keys[4], (2, 5, 128)) * 0.5
         noise = jnp.zeros((2, 5, 128))
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, noise, mode="kwn", k=12)
+        out = _one_step(x, msb, lsb, cb.boundaries, cb.levels, scale, v,
+                        noise, mode="kwn", k=12)
         assert out[1].shape == (2, 5, 128) and out[4].shape == (2, 5)
-        flat = ops.fused_macro_step(x.reshape(10, 256), msb, lsb,
-                                    cb.boundaries, cb.levels, scale,
-                                    v.reshape(10, 128),
-                                    noise.reshape(10, 128), mode="kwn", k=12)
+        flat = _one_step(x.reshape(10, 256), msb, lsb, cb.boundaries,
+                         cb.levels, scale, v.reshape(10, 128),
+                         noise.reshape(10, 128), mode="kwn", k=12)
         np.testing.assert_array_equal(np.asarray(out[1]).reshape(10, 128),
                                       np.asarray(flat[1]))
 
@@ -178,19 +189,16 @@ class TestFusedSeqParity:
         self._assert_seq(t=16, m=8, n_in=512, n_out=256)
 
     def test_t1_seq_equals_step(self):
-        """T=1 degenerate: seq and step entry points agree bitwise."""
+        """T=1 degenerate: the sequence kernel equals the one-step oracle
+        bitwise."""
         x, msb, lsb, cb, scale, v, noise = self._operands(1, 16, 256, 128)
         kw = dict(mode="kwn", k=12, drive_gain=0.25)
         seq = ops.fused_macro_seq(x, msb, lsb, cb.boundaries, cb.levels,
                                   scale, v, noise, **kw)
-        step = ops.fused_macro_step(x[0], msb, lsb, cb.boundaries,
-                                    cb.levels, scale, v, noise[0], **kw)
-        np.testing.assert_array_equal(np.asarray(seq[0][0]),
-                                      np.asarray(step[0]))
-        np.testing.assert_array_equal(np.asarray(seq[1]),
-                                      np.asarray(step[1]))
-        np.testing.assert_array_equal(np.asarray(seq[4][0]),
-                                      np.asarray(step[4]))
+        want = _ref_jit(**kw)(x[0], msb, lsb, cb.boundaries, cb.levels,
+                              scale, v, noise[0])
+        _assert_bitwise((seq[0][0], seq[1], seq[2][0], seq[3][0],
+                         seq[4][0]), want, 128)
 
 
 class TestTilePlanner:
@@ -256,8 +264,8 @@ class TestFusedNldParity:
         v = jax.random.normal(keys[5], (m, n_out)) * 0.5
         noise = jnp.zeros((m, n_out))
         kw = dict(mode="nld", drive_gain=0.25)
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, noise, w_dend=w_dend, **kw)
+        out = _one_step(x, msb, lsb, cb.boundaries, cb.levels, scale, v,
+                        noise, w_dend=w_dend, **kw)
         want = _ref_jit(**kw)(x, msb, lsb, cb.boundaries, cb.levels, scale,
                               v, noise, w_dend)
         _assert_bitwise(out, want, n_out)
@@ -295,19 +303,6 @@ class TestForwardSiliconFused:
                                           np.asarray(tf[name]),
                                           err_msg=f"telemetry {name}")
 
-    def test_step_and_seq_paths_agree(self):
-        """Per-step launches vs one time-major launch: bitwise-identical
-        logits and telemetry (time-major batching is invisible)."""
-        snn, p, ev, cfg = self._setup("kwn")
-        key = jax.random.PRNGKey(2)
-        ls, ts = snn.forward_silicon(p, ev, cfg, key, fused="step")
-        lq, tq = snn.forward_silicon(p, ev, cfg, key, fused="seq")
-        np.testing.assert_array_equal(np.asarray(ls), np.asarray(lq))
-        for name in ts:
-            np.testing.assert_array_equal(np.asarray(ts[name]),
-                                          np.asarray(tq[name]),
-                                          err_msg=f"telemetry {name}")
-
     def test_nld_runs_and_reports_full_ramp(self):
         snn, p, ev, cfg = self._setup("nld")
         logits, tele = snn.forward_silicon(p, ev, cfg, jax.random.PRNGKey(2),
@@ -318,22 +313,43 @@ class TestForwardSiliconFused:
 
     def test_noise_model_stays_fused(self):
         """noise=IMANoiseModel() no longer forces the composed path: the
-        noisy step and seq cadences draw the identical in-kernel counter
-        stream (bitwise-equal logits), and the draws actually perturb the
-        clean result.  Full noisy-oracle parity: tests/test_ima_noise.py."""
+        noisy fused forward is bitwise the counter-based sequence oracle
+        run on the model's packed weights with the model's seed word, and
+        the draws actually perturb the clean result.  Full noisy-oracle
+        parity at the kernel level: tests/test_ima_noise.py."""
+        from repro.core import lif as lif_lib
+        from repro.core import ternary as ternary_lib
         snn, p, ev, cfg = self._setup("kwn")
         noisy = ima_lib.IMANoiseModel()
-        la, ta = snn.forward_silicon(p, ev, cfg, jax.random.PRNGKey(2),
-                                     noise=noisy, fused="step")
-        lb, tb = snn.forward_silicon(p, ev, cfg, jax.random.PRNGKey(2),
-                                     noise=noisy, fused="seq")
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-        for name in ta:
-            np.testing.assert_array_equal(np.asarray(ta[name]),
-                                          np.asarray(tb[name]),
-                                          err_msg=f"telemetry {name}")
-        clean, _ = snn.forward_silicon(p, ev, cfg, jax.random.PRNGKey(2),
-                                       fused="seq")
+        key = jax.random.PRNGKey(2)
+        lb, tb = snn.forward_silicon(p, ev, cfg, key, noise=noisy,
+                                     fused="seq")
+        mcfg = macro_lib.CIMMacroConfig(code_bits=cfg.code_bits,
+                                        mac_range=cfg.mac_range,
+                                        ima_noise=noisy)
+        fw = snn._pack_fused(p, cfg, "kwn", mcfg)
+        lif_p = lif_lib.LIFParams(beta=cfg.beta, v_th1=cfg.v_th1,
+                                  v_th2=cfg.v_th2, noise_amp=cfg.noise_amp)
+        kw = dict(mode="kwn", k=cfg.k, drive_gain=cfg.drive_gain,
+                  beta=cfg.beta, v_th1=cfg.v_th1, v_th2=cfg.v_th2,
+                  v_reset=lif_p.v_reset,
+                  v_lim=lif_lib.vmem_limit(lif_p.vmem_bits),
+                  use_snl=cfg.use_snl,
+                  ima_noise=macro_lib.fused_kernel_noise(fw, mcfg),
+                  snl_amp=lif_p.noise_amp)
+        x = ternary_lib.ternary_input_encode(jnp.moveaxis(ev, 1, 0))
+        v0 = jnp.zeros((ev.shape[0], cfg.n_hidden))
+        _, _, spk, _, steps = jax.jit(functools.partial(
+            ref.fused_macro_seq_ref, **kw))(
+            x, fw.msb, fw.lsb, fw.boundaries, fw.levels, fw.scale, v0, None,
+            seed=snn._noise_seed(key))
+        t = ev.shape[1]
+        want = (jnp.sum(spk, axis=0) / t) @ p["w_out"]
+        np.testing.assert_array_equal(np.asarray(lb), np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(tb["adc_steps"]),
+            np.asarray(jnp.sum(steps[..., 0].astype(jnp.float32), 0) / t))
+        clean, _ = snn.forward_silicon(p, ev, cfg, key, fused="seq")
         assert not np.array_equal(np.asarray(lb), np.asarray(clean))
 
 
@@ -376,30 +392,3 @@ class TestSNNEventEngine:
 
         rep = engine.energy_report("nmnist")
         assert rep["requests"] == 10 and rep["pj_per_sop"] > 0
-
-    def test_time_major_and_per_step_engines_agree(self):
-        from repro.data import events as ev_lib
-        from repro.models import snn
-        from repro.serve.engine import EventRequest, SNNEventEngine
-        dcfg = ev_lib.NMNIST
-        ds = ev_lib.EventDataset(dcfg)
-        cfg = snn.SNNConfig(n_in=dcfg.n_in, n_steps=dcfg.n_steps,
-                            n_classes=dcfg.n_classes, mode="kwn", k=12)
-        p = snn.init_params(cfg, jax.random.PRNGKey(0))
-        ev, lab = ds.sample(jax.random.PRNGKey(1), 3)
-
-        results = {}
-        for time_major in (True, False):
-            # continuous=False: the per-step cadence has no continuous
-            # path, and batch-level PRBS threading only matches between
-            # the two legacy cadences
-            engine = SNNEventEngine(cfg, p, batch_slots=4, seed=5,
-                                    time_major=time_major, continuous=False)
-            for i in range(3):
-                engine.submit(EventRequest(uid=i, events=ev[i],
-                                           label=int(lab[i])))
-            results[time_major] = engine.run()
-        for a, b in zip(results[True], results[False]):
-            np.testing.assert_array_equal(np.asarray(a.logits),
-                                          np.asarray(b.logits))
-            assert a.pred == b.pred and a.adc_steps == b.adc_steps

@@ -183,7 +183,8 @@ def test_tiled_oracle_equals_untiled_sweep(bk, bn):
 
 def test_seq_equals_iterated_step():
     """Time-major batching is bitwise-invisible: one T-step launch equals T
-    single-step launches threading the membrane through HBM."""
+    T = 1 launches threading the membrane through HBM (the continuous
+    engine's rounds split a stream the same way)."""
     t, m, n_in, n_out = 5, 8, 512, 256
     x, msb, lsb, cb, scale, v, noise, _ = _operands(
         11, t, m, n_in, n_out, "kwn", "nlq")
@@ -192,11 +193,11 @@ def test_seq_equals_iterated_step():
                               v, noise, **kw)
     v_c = v
     for step in range(t):
-        mac, v_c, spk, mask, steps = ops.fused_macro_step(
-            x[step], msb, lsb, cb.boundaries, cb.levels, scale, v_c,
-            noise[step], **kw)
+        mac, v_c, spk, mask, steps = ops.fused_macro_seq(
+            x[step][None], msb, lsb, cb.boundaries, cb.levels, scale, v_c,
+            noise[step][None], **kw)
         for name, a, b in zip(("mac", "spikes", "mask", "adc_steps"),
-                              (mac, spk, mask, steps),
+                              (mac[0], spk[0], mask[0], steps[0]),
                               (seq[0][step], seq[2][step], seq[3][step],
                                seq[4][step])):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),

@@ -6,8 +6,8 @@ execution optimization: an all-zero activation block contributes an exactly
 zero partial sum, and the bounded sweep only skips levels with no crossings
 or no admission slots left.  So every output must equal the dense path — and
 the ``ref.py`` oracles — bit for bit, at every event density, in both modes,
-clean and noisy, per-step and time-major, for any tile plan.  This suite
-sweeps that whole matrix; a tolerance here is a bug.
+clean and noisy, for one time step and a whole sequence, for any tile plan.
+This suite sweeps that whole matrix; a tolerance here is a bug.
 
 A curated ``@pytest.mark.fast`` subset (one dense-vs-gated sweep point per
 axis) keeps ``make smoke`` under its 60 s budget; the full matrix runs in
@@ -67,29 +67,31 @@ def _assert_equal(got, want, context):
                                       err_msg=f"{name} mismatch ({context})")
 
 
-def _run_pair(mode, density, cadence, noisy, tiles, seed=0):
-    """Dense vs gated (and vs oracle) for one sweep point."""
+def _run_pair(mode, density, t, noisy, tiles, seed=0):
+    """Dense vs gated (and vs oracle) for one sweep point of ``t`` steps;
+    at t = 1 the oracle is the one-step ``fused_macro_step_ref``."""
     x, msb, lsb, cb, scale, v, noise, w_dend = _operands(
-        mode, density=density, seed=seed)
+        mode, t=t, density=density, seed=seed)
     kw = dict(mode=mode, k=12, drive_gain=0.25, **tiles)
     if noisy:
         kw.update(ima_noise=ima_lib.kernel_noise_params(
             ima_lib.IMANoiseModel(), cb), snl_amp=0.05, seed=7)
         noise = None
-    if cadence == "step":
-        x, noise = x[0], None if noise is None else noise[0]
-        run = ops.fused_macro_step
-        oracle = ref.fused_macro_step_ref
-    else:
-        run = ops.fused_macro_seq
-        oracle = ref.fused_macro_seq_ref
     args = (x, msb, lsb, cb.boundaries, cb.levels, scale, v, noise, w_dend)
-    dense = run(*args, gate=False, **kw)
-    gated = run(*args, gate=True, **kw)
-    gated_dark = run(*args, gate=True, mac_telemetry=False, **kw)
+    dense = ops.fused_macro_seq(*args, gate=False, **kw)
+    gated = ops.fused_macro_seq(*args, gate=True, **kw)
+    gated_dark = ops.fused_macro_seq(*args, gate=True, mac_telemetry=False,
+                                     **kw)
     okw = {k: v_ for k, v_ in kw.items() if k not in ("bm", "bk", "bn")}
-    want = jax.jit(functools.partial(oracle, **okw))(*args)
-    ctx = f"{mode}/{cadence}/d={density}/noisy={noisy}/tiles={tiles}"
+    if t == 1:
+        want = jax.jit(functools.partial(ref.fused_macro_step_ref, **okw))(
+            x[0], *args[1:7], None if noise is None else noise[0], w_dend)
+        want = (want[0], want[1], want[2][None], want[3][None],
+                want[4][None])
+    else:
+        want = jax.jit(functools.partial(ref.fused_macro_seq_ref, **okw))(
+            *args)
+    ctx = f"{mode}/t={t}/d={density}/noisy={noisy}/tiles={tiles}"
     # gated == dense, bitwise, including the raw MAC telemetry
     _assert_equal(gated[1:], dense[1:], ctx)
     np.testing.assert_array_equal(np.asarray(gated[0]), np.asarray(dense[0]),
@@ -103,33 +105,34 @@ def _run_pair(mode, density, cadence, noisy, tiles, seed=0):
 
 
 class TestGatedParitySweep:
-    """The acceptance matrix: density x mode x cadence x noise x tiling."""
+    """The acceptance matrix: density x mode x length x noise x tiling."""
 
     @pytest.mark.parametrize("density", [
         pytest.param(0.0, marks=pytest.mark.fast), 0.01,
         pytest.param(0.1, marks=pytest.mark.fast), 1.0])
     def test_kwn_seq_clean_density_sweep(self, density):
-        _run_pair("kwn", density, "seq", noisy=False, tiles={})
+        _run_pair("kwn", density, 3, noisy=False, tiles={})
 
     @pytest.mark.parametrize("density", DENSITIES)
     @pytest.mark.parametrize("mode", ["kwn", "nld"])
-    @pytest.mark.parametrize("cadence", ["step", "seq"])
+    @pytest.mark.parametrize("t", [pytest.param(1, id="step"),
+                                   pytest.param(3, id="seq")])
     @pytest.mark.parametrize("noisy", [False, True])
-    def test_full_matrix_default_tiles(self, density, mode, cadence, noisy):
-        _run_pair(mode, density, cadence, noisy, tiles={})
+    def test_full_matrix_default_tiles(self, density, mode, t, noisy):
+        _run_pair(mode, density, t, noisy, tiles={})
 
     @pytest.mark.parametrize("density", [0.0, 0.1])
     @pytest.mark.parametrize("mode", ["kwn", "nld"])
     @pytest.mark.parametrize("noisy", [False, True])
     def test_multi_tile_plan(self, density, mode, noisy):
-        _run_pair(mode, density, "seq", noisy, tiles=TILE_PLANS[1])
+        _run_pair(mode, density, 3, noisy, tiles=TILE_PLANS[1])
 
     @pytest.mark.fast
     def test_fast_cross_section(self):
         """One noisy multi-tile point for the smoke tier (the remaining
-        axes — nld, step cadence, full tile sweep — run in the default
-        tier via the matrix above)."""
-        _run_pair("kwn", 0.1, "seq", noisy=True, tiles=TILE_PLANS[1])
+        axes — nld, t = 1, full tile sweep — run in the default tier via
+        the matrix above)."""
+        _run_pair("kwn", 0.1, 3, noisy=True, tiles=TILE_PLANS[1])
 
 
 class TestActivityMap:
@@ -208,16 +211,6 @@ class TestModelAndServingTelemetry:
                                        jax.random.PRNGKey(2), fused="seq")
         np.testing.assert_allclose(
             np.asarray(tele0["skipped_block_ratio"]), 1.0)
-
-    def test_step_and_seq_report_identical_ratio(self):
-        snn, p, ev, _, cfg = self._setup()
-        _, ts = snn.forward_silicon(p, ev, cfg, jax.random.PRNGKey(2),
-                                    fused="step")
-        _, tq = snn.forward_silicon(p, ev, cfg, jax.random.PRNGKey(2),
-                                    fused="seq")
-        np.testing.assert_array_equal(
-            np.asarray(ts["skipped_block_ratio"]),
-            np.asarray(tq["skipped_block_ratio"]))
 
     def test_mac_telemetry_opt_in_is_output_invariant(self):
         snn, p, ev, _, cfg = self._setup()

@@ -65,18 +65,27 @@ class TestKwnEarlyStopGolden:
                                       np.asarray(sel.mask))
 
     def test_kernel_agrees(self):
-        """The Pallas kernel's step counts are the energy model's input —
-        pin them to the same golden histogram."""
+        """The fused kernel's step counts are the energy model's input —
+        pin them to the same golden histogram, through the dense ramp
+        sweep (``gate=False``; the gated sweep is pinned below)."""
+        from repro.core import ternary as ternary_lib
+        x, w = _golden_inputs()
+        msb, lsb = ternary_lib.weight_decompose(jnp.asarray(w))
         cb = self._cb()
-        _, steps = ops.kwn_topk(_golden_mac(), cb.boundaries, 12)
+        v = jnp.zeros((64, 128))
+        out = ops.fused_macro_seq(
+            jnp.asarray(x)[None], msb.astype(jnp.int8), lsb.astype(jnp.int8),
+            cb.boundaries, cb.levels, jnp.ones((128,)), v,
+            jnp.zeros((1,) + v.shape), mode="kwn", k=12, gate=False)
         np.testing.assert_array_equal(
-            np.bincount(np.asarray(steps), minlength=32), GOLDEN_STEP_HIST)
+            np.bincount(np.asarray(out[4][0]), minlength=32),
+            GOLDEN_STEP_HIST)
 
 
 class TestTilingInvarianceGolden:
     """Tiling and time-major batching must not move the measured early-stop
     statistics (or the pJ/SOP figures derived from them): the same golden
-    MAC inputs produce the identical PR 1 step histogram whether the fused
+    MAC inputs produce the identical step histogram whether the fused
     kernel runs one step on one macro-wide tile, a forced multi-tile grid,
     or a whole time-major sequence."""
 
@@ -95,21 +104,23 @@ class TestTilingInvarianceGolden:
     def _hist(self, steps):
         return np.bincount(np.asarray(steps).reshape(-1), minlength=32)
 
-    def test_fused_step_histogram_matches_golden(self):
+    def _one_step(self, **kw):
+        """One time step: a T = 1 launch of the sequence kernel."""
         x, msb, lsb, cb, scale, v = self._operands()
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, jnp.zeros_like(v),
-                                   mode="kwn", k=self.K_WIN)
+        return ops.fused_macro_seq(x[None], msb, lsb, cb.boundaries,
+                                   cb.levels, scale, v,
+                                   jnp.zeros((1,) + v.shape), mode="kwn",
+                                   k=self.K_WIN, **kw)
+
+    def test_fused_step_histogram_matches_golden(self):
+        out = self._one_step()
         np.testing.assert_array_equal(self._hist(out[4]), GOLDEN_STEP_HIST)
 
     def test_forced_tiling_histogram_invariant(self):
         """bk=64, bn=32 forces a 4x4 (K, col) tile grid over the same
         macro: digital partial-sum accumulation must not move a single
         histogram bin."""
-        x, msb, lsb, cb, scale, v = self._operands()
-        out = ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                   scale, v, jnp.zeros_like(v),
-                                   mode="kwn", k=self.K_WIN, bk=64, bn=32)
+        out = self._one_step(bk=64, bn=32)
         np.testing.assert_array_equal(self._hist(out[4]), GOLDEN_STEP_HIST)
         assert float(np.asarray(out[4]).mean()) == GOLDEN_MEAN_STEPS
 
@@ -135,9 +146,7 @@ class TestTilingInvarianceGolden:
         ref_steps = kwn_lib.kwn_select(_golden_mac(), self.K_WIN,
                                        cb).adc_steps
         variants = [
-            ops.fused_macro_step(x, msb, lsb, cb.boundaries, cb.levels,
-                                 scale, v, jnp.zeros_like(v), mode="kwn",
-                                 k=self.K_WIN, bk=64, bn=32)[4],
+            self._one_step(bk=64, bn=32)[4][0],
             ops.fused_macro_seq(jnp.broadcast_to(x, (2,) + x.shape), msb,
                                 lsb, cb.boundaries, cb.levels, scale, v,
                                 jnp.zeros((2,) + v.shape), mode="kwn",

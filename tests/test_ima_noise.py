@@ -143,8 +143,8 @@ class TestSeededDeterminism:
 
     @pytest.mark.fast
     def test_step_offset_reproduces_seq_stream(self):
-        """A per-step launch cadence feeding the scan index as step_offset
-        draws the exact one-launch sequence stream."""
+        """T = 1 launches that carry the membrane and pass the step index
+        as step_offset draw the exact one-launch sequence stream."""
         x, msb, lsb, cb, scale, v = _kwn_operands(4, 16, 256, 128)
         kw = dict(mode="kwn", k=12, drive_gain=0.25,
                   ima_noise=_noise_params(cb), snl_amp=0.05, seed=21)
@@ -152,10 +152,10 @@ class TestSeededDeterminism:
                                   scale, v, None, **kw)
         vv, spk = v, []
         for t in range(4):
-            _, vv, s, _, _ = ops.fused_macro_step(
-                x[t], msb, lsb, cb.boundaries, cb.levels, scale, vv, None,
-                step_offset=t, **kw)
-            spk.append(np.asarray(s))
+            _, vv, s, _, _ = ops.fused_macro_seq(
+                x[t][None], msb, lsb, cb.boundaries, cb.levels, scale, vv,
+                None, step_offset=t, **kw)
+            spk.append(np.asarray(s[0]))
         np.testing.assert_array_equal(np.stack(spk), np.asarray(seq[2]))
         np.testing.assert_array_equal(np.asarray(vv), np.asarray(seq[1]))
 

@@ -250,16 +250,17 @@ class TestContinuousScheduling:
     @pytest.mark.fast
     def test_continuous_rejects_unsupported_configs(self):
         cfg, p = _setup()
-        with pytest.raises(ValueError):
-            SNNEventEngine(cfg, p, time_major=False, continuous=True)
-        # auto-select falls back instead of raising
-        eng = SNNEventEngine(cfg, p, time_major=False)
-        assert not eng.continuous
+        assert SNNEventEngine(cfg, p).continuous
         cfg2 = snn_lib.SNNConfig(n_in=16, n_hidden=8, n_classes=2,
                                  hidden_layers=(8, 8), k_layers=(2, 2))
         p2 = snn_lib.init_params(cfg2, jax.random.PRNGKey(0))
+        with pytest.raises(ValueError):
+            SNNEventEngine(cfg2, p2, batch_slots=2, continuous=True)
+        # auto-select falls back instead of raising
         eng2 = SNNEventEngine(cfg2, p2, batch_slots=2)
         assert not eng2.continuous        # stacks serve via the drain path
+        with pytest.raises(TypeError):    # no cadence option
+            SNNEventEngine(cfg, p, **{"time_major": False})
 
     @pytest.mark.fast
     def test_energy_report_per_request_columns(self):

@@ -147,6 +147,19 @@ def test_stack_256_128_at_32_rows(chip):
         _params(chip, cfg), events, key)
 
 
+def test_stack_dvs_width_64_rows(chip):
+    """The ``dvs-stack`` cell's drain-path batch: the stacked KWN kernel at
+    the DVS128 sensor width, 32768 inputs into layers (256, 128) with
+    k = 12, 64 rows, T = 30, clean."""
+    cfg = snn.SNNConfig(n_in=32768, hidden_layers=(256, 128), n_classes=11,
+                        n_steps=30, k=12)
+    events = _on(chip, jax.ShapeDtypeStruct((64, 30, cfg.n_in), jnp.float32))
+    key = _on(chip, _KEY)
+    _compile(chip, lambda p, ev, k: snn.forward_silicon(p, ev, cfg, k,
+                                                  fused="seq"),
+             _params(chip, cfg), events, key)
+
+
 def test_silicon_vjp_batch_64(chip):
     """One silicon training gradient at batch 64: the fused forward with
     its training trace, then the BPTT kernel, noise-aware."""
